@@ -9,7 +9,6 @@ pools instead of each keeping a private copy-pasted sampler.
 from __future__ import annotations
 
 import time
-from pathlib import Path
 
 import numpy as np
 
@@ -47,17 +46,11 @@ def emit(name: str, us_per_call: float, derived: str) -> str:
 
 
 def load_agent():
-    """(scheduler, trained) — the agent every bench scores.
-
-    Precedence: a local training output under ``artifacts/`` (a dev
-    override — your own ``examples/train_respect.py`` run wins on your
-    box), then the checked-in **trained release checkpoint**
-    (``checkpoints/respect-v*``, integrity-verified — what CI and fresh
-    clones get), then seeded untrained weights with a warning."""
+    """(scheduler, trained) — the agent every bench scores: the checked-in
+    **trained release checkpoint** (``checkpoints/respect-v*``,
+    integrity-verified), else seeded untrained weights with a warning.
+    Nothing is picked up from local directories git does not track, so a
+    checkout scores exactly the weights its commit holds."""
     from repro.core import RespectScheduler
-    for path in (Path("artifacts/respect_agent"),
-                 Path("artifacts/respect_agent.npz")):
-        if path.exists():
-            return RespectScheduler.load(path), True
     sched = RespectScheduler.from_release()   # warns on seeded fallback
     return sched, sched.release is not None

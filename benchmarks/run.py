@@ -36,6 +36,8 @@ def main() -> int:
                          "vs fused; --smoke default: BENCH_serve.json)")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache(Path(__file__).resolve().parent.parent)
     from . import (batched_schedule_bench, decode_kernel_bench, eval_grid,
                    fig3_solving_time, fig4_inference_runtime,
                    fig5_gap_to_optimal, ingest_bench, kernels_bench,

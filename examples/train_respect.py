@@ -20,8 +20,8 @@ paper's setup (hidden 256, batch 128, lr 1e-4 Adam).
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         PYTHONPATH=src python examples/train_respect.py --devices 8
 
-Outputs: artifacts/respect_agent (checkpoint-manager format, used by
-benchmarks/) + metrics JSONL + periodic trainer checkpoints under
+Outputs: artifacts/respect_agent (checkpoint-manager format; load it with
+``RespectScheduler.load``) + metrics JSONL + periodic trainer checkpoints under
 --ckpt-dir (resumable: kill and re-run to continue).
 """
 

@@ -58,6 +58,7 @@ from repro.core.batching import bucket_for  # noqa: E402
 from repro.core.rl import RLTrainer, pack_graphs  # noqa: E402
 from repro.core.sampler import sample_dag  # noqa: E402
 from repro.eval.scenarios import SYNTH_FAMILIES, synthetic_dag  # noqa: E402
+from repro.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 # curriculum topology mixture: the paper sampler + the eval families
 FAMILY_MIX = ("paper",) + SYNTH_FAMILIES
@@ -134,6 +135,7 @@ def main() -> int:
     ap.add_argument("--save-every", type=int, default=200)
     ap.add_argument("--devices", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache(Path(__file__).resolve().parent.parent)
     stage_counts = tuple(int(s) for s in args.stage_counts.split(","))
 
     base = PipelineSystem(n_stages=stage_counts[0])

@@ -26,4 +26,4 @@ from .postprocess import repair  # noqa: F401
 from .respect import RespectScheduler  # noqa: F401
 from .rho import rho  # noqa: F401
 from .sampler import DagSampler, prefetch, sample_batch, sample_dag  # noqa: F401
-from .segment import exact_dp_batch, exact_dp_jax, repair_jax, rho_dp_batch, rho_dp_jax  # noqa: F401
+from .segment import exact_dp_batch, exact_dp_jax, repair_jax, rho_dp_jax  # noqa: F401

@@ -35,7 +35,6 @@ from __future__ import annotations
 import dataclasses
 import os
 import threading
-import warnings
 from collections import OrderedDict
 
 import jax
@@ -358,10 +357,11 @@ class BucketedDecoder:
     "kernel-interpret" the same kernel through the Pallas interpreter
     (CPU-testable), and None auto-picks per bucket: the kernel on TPU
     when :func:`repro.kernels.ptr.ops.decode_kernel_supported` accepts
-    the (bucket, hidden) shape, the scan everywhere else.  A forced
-    "kernel" on an unsupported shape falls back to the scan with a
-    single warning instead of failing.  The ``RESPECT_DECODE_IMPL`` env
-    var overrides the default when no explicit argument is given.
+    the (bucket, hidden) shape and the system is uniform, the scan
+    everywhere else.  A forced "kernel" on a backend, shape or
+    heterogeneous system it cannot run raises ``ValueError``.
+    The ``RESPECT_DECODE_IMPL`` env var overrides the default when no
+    explicit argument is given.
     ``decode_bf16`` stores the kernel's context/projection blocks in
     bfloat16 (f32 accumulation; kernel paths only, default off).
     """
@@ -383,8 +383,6 @@ class BucketedDecoder:
         self.decode_impl = decode_impl
         self.decode_bf16 = decode_bf16
         self._fns = _LRU(max_compiled)
-        self._warned_fallback = False
-        self._warned_hetero = False
 
     # ------------------------------------------------------------------ #
     def _logits_builder(self):
@@ -402,18 +400,17 @@ class BucketedDecoder:
 
         ``conditioned`` marks a profile-conditioned decode (heterogeneous /
         capacity-constrained system): the whole-decode kernel has no system
-        input, so those programs always run the scan path.
+        input, so auto runs those programs on the scan path and a forced
+        kernel raises ``ValueError``.
         """
         from ..kernels.ptr import ops as ptr_ops
         if conditioned:
-            if (self.decode_impl in ("kernel", "kernel-interpret")
-                    and not self._warned_hetero):
-                self._warned_hetero = True
-                warnings.warn(
-                    "profile-conditioned decode (heterogeneous system) is "
-                    "not supported by the whole-decode kernel; using the "
-                    "scan path for these programs",
-                    RuntimeWarning, stacklevel=3)
+            if self.decode_impl in ("kernel", "kernel-interpret"):
+                raise ValueError(
+                    f"decode_impl={self.decode_impl!r} cannot run a profile-"
+                    "conditioned (heterogeneous or memory-capped) system: "
+                    "the whole-decode kernel has no system input; leave "
+                    "decode_impl unset to pick the scan for it")
             return "scan"
         impl = self.decode_impl
         if impl is None:
@@ -422,22 +419,16 @@ class BucketedDecoder:
                 return "kernel"
             return "scan"
         if impl == "kernel":
-            reason = None
             if jax.default_backend() != "tpu":
-                reason = (f"compiled Pallas is TPU-only (backend="
-                          f"{jax.default_backend()}); use "
-                          "'kernel-interpret' to exercise the kernel here")
-            elif not ptr_ops.decode_kernel_supported(bucket_n, hidden):
-                reason = (f"bucket_n={bucket_n}, hidden={hidden} does not "
-                          "tile/fit VMEM")
-            if reason is not None:
-                if not self._warned_fallback:
-                    self._warned_fallback = True
-                    warnings.warn(
-                        f"decode_impl='kernel' unavailable: {reason}; "
-                        "falling back to the scan path",
-                        RuntimeWarning, stacklevel=3)
-                return "scan"
+                raise ValueError(
+                    f"decode_impl='kernel' is the compiled TPU kernel "
+                    f"(backend={jax.default_backend()}); use "
+                    "'kernel-interpret' to run the kernel here")
+            if not ptr_ops.decode_kernel_supported(bucket_n, hidden):
+                raise ValueError(
+                    f"decode_impl='kernel' cannot run bucket_n={bucket_n}, "
+                    f"hidden={hidden}: the blocks do not tile or fit VMEM; "
+                    "leave decode_impl unset to pick the scan for it")
         return impl
 
     @staticmethod

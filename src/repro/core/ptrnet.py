@@ -43,6 +43,8 @@ __all__ = [
     "decode",
     "greedy_order",
     "sample_order",
+    "inverse_cdf_pick",
+    "policy_precision",
     "NEG_INF",
 ]
 
@@ -172,6 +174,32 @@ def _pointer_logits_hoisted(params, ref_g, ref_p, C, h, mask):
     return jnp.where(mask, logits, NEG_INF)
 
 
+def inverse_cdf_pick(probs, u):
+    """Inverse-CDF categorical pick: the first index whose CDF prefix
+    exceeds ``u * total``, else the last index with nonzero probability.
+
+    probs: (n, 1) f32 column; u: scalar uniform.  Returns the index as an
+    f32 scalar (exact for any realistic n).  The prefix sums are a
+    compare-and-sum against a triangular mask rather than ``cumsum``: the
+    TPU kernel compiler has no ``cumsum`` lowering, and the scan decode and
+    the whole-decode kernel share this one expression so that their
+    sampled picks stay bit-identical.
+    """
+    n = probs.shape[0]
+    f32 = jnp.float32
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    cdf = jnp.sum(jnp.where(rows <= cols, probs, 0.0), axis=0,
+                  keepdims=True)                              # (1, n)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1).astype(f32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0).astype(f32)
+    total = jnp.sum(jnp.where(col == n - 1.0, cdf, 0.0))
+    draw = u * total
+    idx = jnp.min(jnp.where(cdf > draw, col, f32(n)))
+    last_live = jnp.max(jnp.where(probs > 0, row, -1.0))
+    return jnp.where(total > draw, idx, last_live)
+
+
 def decode(
     params,
     C,
@@ -248,16 +276,13 @@ def decode(
         logprobs = jax.nn.log_softmax(logits)
         if sample_key is not None:
             # inverse-CDF categorical draw from ONE scalar uniform.  Masked
-            # slots carry exactly-zero probability, so the cumsum prefix —
+            # slots carry exactly-zero probability, so the CDF prefix —
             # and hence the sampled index — is identical for the padded and
             # unpadded decode of the same graph (gumbel-based sampling is
             # not: its noise vector depends on the padded length).
-            probs_cdf = jnp.cumsum(jnp.exp(logprobs))
-            t = jax.random.uniform(key, ()) * probs_cdf[-1]
-            idx = jnp.argmax(probs_cdf > t)
-            last_live = jnp.argmax(
-                jnp.where(jnp.exp(logprobs) > 0, jnp.arange(n), -1))
-            idx = jnp.where(probs_cdf[-1] > t, idx, last_live)
+            idx = inverse_cdf_pick(
+                jnp.exp(logprobs)[:, None],
+                jax.random.uniform(key, ())).astype(jnp.int32)
         else:
             idx = jnp.argmax(logits)
         probs = jnp.exp(logprobs)
@@ -277,32 +302,42 @@ def decode(
     return order.astype(jnp.int32), logp, ent
 
 
+def policy_precision():
+    """Context under which the policy is traced: full-f32 matmuls on every
+    backend.  A TPU's default f32 matmul is one bf16 pass, which moves the
+    logits of the f32-trained release enough to change greedy picks; dots
+    traced under this context carry ``Precision.HIGHEST``, inside the
+    Pallas kernels too.  CPU results are unchanged."""
+    return jax.default_matmul_precision("highest")
+
+
 def _run(params, feats, parent_mat, sample_key, mask_infeasible, n_valid,
          logits_builder=None, decode_builder=None, unroll: int = 1,
          sys_feat=None):
-    C, enc_state, emb = encode(params, feats, n_valid=n_valid,
-                               unroll=unroll)
-    if decode_builder is not None:
-        # whole-decode hook: the builder's decode_fn replaces the entire
-        # per-step scan (e.g. the persistent Pallas kernel,
-        # repro.kernels.ptr.decode.make_decode_fn) — it owns masking,
-        # argmax/sampling and the drain semantics end to end.
-        if sys_feat is not None:
-            raise ValueError(
-                "decode_builder kernels do not take a system profile; "
-                "select the scan decode for heterogeneous systems")
-        decode_fn = decode_builder(params)
-        return decode_fn(
+    if decode_builder is not None and sys_feat is not None:
+        raise ValueError(
+            "decode_builder kernels do not take a system profile; "
+            "select the scan decode for heterogeneous systems")
+    with policy_precision():
+        C, enc_state, emb = encode(params, feats, n_valid=n_valid,
+                                   unroll=unroll)
+        if decode_builder is not None:
+            # whole-decode hook: the builder's decode_fn replaces the entire
+            # per-step scan (e.g. the persistent Pallas kernel,
+            # repro.kernels.ptr.decode.make_decode_fn) — it owns masking,
+            # argmax/sampling and the drain semantics end to end.
+            return decode_builder(params)(
+                params, C, emb, enc_state, parent_mat,
+                sample_key=sample_key, mask_infeasible=mask_infeasible,
+                n_valid=n_valid)
+        logits_fn = (None if logits_builder is None
+                     else logits_builder(params, C))
+        return decode(
             params, C, emb, enc_state, parent_mat,
             sample_key=sample_key, mask_infeasible=mask_infeasible,
-            n_valid=n_valid)
-    logits_fn = None if logits_builder is None else logits_builder(params, C)
-    return decode(
-        params, C, emb, enc_state, parent_mat,
-        sample_key=sample_key, mask_infeasible=mask_infeasible,
-        logits_fn=logits_fn, n_valid=n_valid, unroll=unroll,
-        sys_feat=sys_feat,
-    )
+            logits_fn=logits_fn, n_valid=n_valid, unroll=unroll,
+            sys_feat=sys_feat,
+        )
 
 
 def greedy_order(params, feats, parent_mat, mask_infeasible=True,

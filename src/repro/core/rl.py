@@ -53,7 +53,7 @@ from .batching import PaddedGraphBatch, bucket_for, pack_padded
 from .costmodel import PipelineSystem
 from .exact import exact_bb, order_from_assignment
 from .graph import CompGraph
-from .segment import rho_dp_batch, rho_dp_jax  # noqa: F401  (serving twins)
+from .segment import exact_dp_batch, rho_dp_jax
 
 __all__ = [
     "label_graphs",
@@ -73,20 +73,16 @@ __all__ = [
 # exact labeling (vmapped pad-aware DP, on-disk cache)
 # --------------------------------------------------------------------- #
 @functools.lru_cache(maxsize=32)
-def _dp_label_fn(bucket_n: int, n_stages: int, system: PipelineSystem):
-    """Jitted vmapped exact-DP labeler for one size bucket: graphs of any
-    ``n <= bucket_n`` solve together in ONE program (identity order — node
-    indices are topological by CompGraph construction, exactly the order
-    :func:`repro.core.exact.exact_dp` segments by default; padded trailing
-    slots are zero-cost, so the valid prefix matches the unpadded solve
-    bit-for-bit)."""
-    order = jnp.arange(bucket_n, dtype=jnp.int32)
-
-    def batched(fl, pb, ob, pmat, nv):
-        orders = jnp.broadcast_to(order, (fl.shape[0], bucket_n))
-        return rho_dp_batch(orders, fl, pb, ob, pmat, n_stages, system, nv)
-
-    return jax.jit(batched)
+def _dp_label_fn(n_stages: int, system: PipelineSystem):
+    """Jitted vmapped exact-DP labeler
+    (:func:`repro.core.segment.exact_dp_batch`): graphs of any
+    ``n <= bucket_n`` solve together in ONE program per bucket (identity
+    order — node indices are topological by CompGraph
+    construction, exactly the order :func:`repro.core.exact.exact_dp`
+    segments by default; padded trailing slots are zero-cost, so the valid
+    prefix matches the unpadded solve bit-for-bit)."""
+    return jax.jit(lambda fl, pb, ob, pmat, nv: exact_dp_batch(
+        fl, pb, ob, pmat, n_stages, system, nv))
 
 
 def _label_cache_key(g: CompGraph, n_stages: int, system: PipelineSystem,
@@ -166,7 +162,7 @@ def label_graphs(
                     ob[row, : g.n] = g.out_bytes
                     pmat[row, : g.n] = g.parent_matrix(max_deg)
                     nv[row] = g.n
-                assigns, _ = _dp_label_fn(bucket_n, n_stages, system)(
+                assigns, _ = _dp_label_fn(n_stages, system)(
                     jnp.asarray(fl), jnp.asarray(pb), jnp.asarray(ob),
                     jnp.asarray(pmat), jnp.asarray(nv))
                 assigns = np.asarray(assigns, dtype=np.int64)
@@ -411,7 +407,6 @@ def make_train_step(
 
         return train_step
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     n_dev = mesh.shape[axis_name]
 
@@ -431,11 +426,11 @@ def make_train_step(
                 f"global batch {batch.batch} not divisible by "
                 f"{n_dev} devices on mesh axis {axis_name!r}")
         keys = jax.random.split(key, batch.batch)
-        loss_sum, sums, grads = shard_map(
+        loss_sum, sums, grads = jax.shard_map(
             sharded_grads, mesh=mesh,
             in_specs=(P(), P(), P(axis_name), P(axis_name)),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(params, baseline_params, batch, keys)
         return _finish(params, opt_state, loss_sum, sums, grads)
 

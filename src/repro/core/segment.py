@@ -36,7 +36,6 @@ from .costmodel import CAPACITY_PENALTY_S, PipelineSystem
 
 __all__ = [
     "rho_dp_jax",
-    "rho_dp_batch",
     "exact_dp_jax",
     "exact_dp_batch",
     "dependency_repair_jax",
@@ -72,9 +71,7 @@ def exact_dp_jax(
     (:class:`repro.eval.oracle.ExactOracle`), which is what makes the
     oracle's bottleneck/latency bit-identical to the host reference.
     """
-    n = flops.shape[0]
-    order = jnp.arange(n, dtype=jnp.int32)
-    return rho_dp_jax(order, flops, param_bytes, out_bytes, parent_mat,
+    return rho_dp_jax(None, flops, param_bytes, out_bytes, parent_mat,
                       n_stages, system, n_valid=n_valid)
 
 
@@ -91,22 +88,6 @@ def exact_dp_batch(flops, param_bytes, out_bytes, parent_mat,
         return exact_dp_jax(fl, pb, ob, pm, n_stages, system, n_valid=nv)
 
     return jax.vmap(one)(flops, param_bytes, out_bytes, parent_mat, n_valid)
-
-
-def rho_dp_batch(orders, flops, param_bytes, out_bytes, parent_mat,
-                 n_stages: int, system, n_valid):
-    """vmapped pad-aware :func:`rho_dp_jax` over a padded batch.
-
-    All array args carry a leading batch dim (``orders`` is ``(B, n)`` etc.,
-    ``n_valid`` is ``(B,)``); one XLA program segments every graph in the
-    pack — the shared primitive under the vmapped DP labeler, the RL reward
-    and the fused serving path.
-    """
-    def one(o, fl, pb, ob, pm, nv):
-        return rho_dp_jax(o, fl, pb, ob, pm, n_stages, system, n_valid=nv)
-
-    return jax.vmap(one)(orders, flops, param_bytes, out_bytes, parent_mat,
-                         n_valid)
 
 
 def rho_dp_jax(
@@ -131,14 +112,21 @@ def rho_dp_jax(
     both).  Padded positions then contribute zero cost to every segment —
     including the per-stage dispatch overhead, which counts *real* nodes
     only — so the real-node assignment equals the unpadded DP's.
+
+    ``order=None`` is the identity order (the exact DP).  It skips the
+    permutation scatters: a scatter of an iota by itself is refused by
+    the TPU compiler.
     """
-    n = order.shape[0]
+    n = flops.shape[0]
     k = n_stages
     nv = jnp.asarray(n if n_valid is None else n_valid, jnp.int32)
-    pos = jnp.zeros(n, jnp.int32).at[order].set(jnp.arange(n, dtype=jnp.int32))
-
-    f_ord = flops[order]
-    p_ord = param_bytes[order]
+    if order is None:
+        pos = jnp.arange(n, dtype=jnp.int32)
+        f_ord, p_ord = flops, param_bytes
+    else:
+        pos = jnp.zeros(n, jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32))
+        f_ord, p_ord = flops[order], param_bytes[order]
     cf = jnp.concatenate([jnp.zeros(1), jnp.cumsum(f_ord)])
     cp = jnp.concatenate([jnp.zeros(1), jnp.cumsum(p_ord)])
 
@@ -230,6 +218,8 @@ def rho_dp_jax(
         i = splits[s - 1][j].astype(jnp.int32)
         assign_pos = jnp.where((positions >= i) & (positions < j), s, assign_pos)
         j = i
+    if order is None:
+        return assign_pos, f_b[n]
     assign = jnp.zeros(n, jnp.int32).at[order].set(assign_pos)
     return assign, f_b[n]
 

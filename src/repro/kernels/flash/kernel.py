@@ -28,12 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-only helpers; fall back for CPU interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_pallas"]
 
@@ -118,12 +113,10 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         _flash_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, num_k_blocks=nk, sk=sk, sq=sq)
 
-    if _VMEM is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU helpers unavailable")
     scratch = [
-        _VMEM((block_q,), jnp.float32),    # running max m
-        _VMEM((block_q,), jnp.float32),    # normalizer l
-        _VMEM((block_q, dv), jnp.float32), # fp32 accumulator
+        pltpu.VMEM((block_q,), jnp.float32),     # running max m
+        pltpu.VMEM((block_q,), jnp.float32),     # normalizer l
+        pltpu.VMEM((block_q, dv), jnp.float32),  # fp32 accumulator
     ]
 
     return pl.pallas_call(

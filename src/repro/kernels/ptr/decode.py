@@ -12,7 +12,8 @@ the whole pointing episode — decoder LSTM update, visited/validity/
 infeasibility masking, glimpse attention, pointer logits, argmax or
 inverse-CDF sample, log-prob/entropy bookkeeping — without touching HBM.
 
-TPU-friendly formulation (no gathers, no 1D iota, everything 2D):
+TPU-friendly formulation (no gathers, no 1D iota, everything 2D; iotas
+are built in int32 and converted, since Mosaic has no float iota):
 
 * node-indexed vectors live on sublanes as ``(n, 1)`` columns (visited,
   mask, scores, per-step outputs); latent rows are ``(1, H)``;
@@ -26,8 +27,9 @@ TPU-friendly formulation (no gathers, no 1D iota, everything 2D):
 
 The sampled variant consumes ONE precomputed uniform per step
 (:func:`step_uniforms`), drawn from exactly the per-step ``fold_in`` key
-stream the scan decode uses — so the padded/unpadded sampling contract
-(PR 3) carries over unchanged.
+stream the scan decode uses, and picks through the scan's own
+:func:`repro.core.ptrnet.inverse_cdf_pick` — so the padded/unpadded
+sampling contract carries over unchanged.
 
 ``bf16=True`` stores the four big per-graph operands (``C``, the two
 projections, ``emb``) in bfloat16 — halving their VMEM footprint — while
@@ -46,7 +48,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ...core.ptrnet import inverse_cdf_pick, policy_precision
 from . import ops as _ops
 
 __all__ = [
@@ -103,7 +107,7 @@ def _decode_kernel(C_ref, CWg_ref, CWp_ref, emb_ref, padj_ref, valid_ref,
     vp = vp_ref[...].astype(f32)      # (H, 1)
 
     n, hidden = C.shape
-    iota = jax.lax.broadcasted_iota(f32, (n, 1), 0)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0).astype(f32)
     n_parents = jnp.sum(padj, axis=1, keepdims=True)          # (n, 1)
     dot = functools.partial(jnp.dot, preferred_element_type=f32)
 
@@ -147,15 +151,8 @@ def _decode_kernel(C_ref, CWg_ref, CWp_ref, emb_ref, padj_ref, valid_ref,
         probs = jnp.exp(logprobs)
 
         if sampled:
-            cdf = jnp.cumsum(probs, axis=0)                   # (n, 1)
-            t_f = t.astype(f32)
-            u = jnp.sum(jnp.where(iota == t_f, unif, 0.0))
-            cdf_last = jnp.sum(jnp.where(iota == n - 1.0, cdf, 0.0))
-            draw = u * cdf_last
-            # first index whose CDF prefix exceeds the draw
-            idx = jnp.min(jnp.where(cdf > draw, iota, f32(n)))
-            last_live = jnp.max(jnp.where(probs > 0, iota, -1.0))
-            idx = jnp.where(cdf_last > draw, idx, last_live)
+            u = jnp.sum(jnp.where(iota == t.astype(f32), unif, 0.0))
+            idx = inverse_cdf_pick(probs, u)
         else:
             # first-occurrence argmax — the scan's jnp.argmax tie-break
             idx = jnp.min(jnp.where(logits == l_max, iota, f32(n)))
@@ -201,61 +198,65 @@ def decode_batch(params, C, emb, h0, c0, parent_mat, n_valid,
     Returns (order (B, n) int32, logp (B, n) f32, ent (B, n) f32) with
     the scan decode's exact semantics (drained pads at zero logp/ent).
     """
-    B, n, hidden = C.shape
-    if sampled and uniforms is None:
-        raise ValueError("sampled decode needs per-step uniforms")
-    CWg, CWp = _ops.precompute_refs(params, C)
-    padj = parent_adjacency(parent_mat, n)
-    valid = (jnp.arange(n)[None, :] < n_valid[:, None]) \
-        .astype(jnp.float32)[..., None]                       # (B, n, 1)
-    unif = (jnp.zeros((B, n, 1), jnp.float32) if uniforms is None
-            else uniforms.astype(jnp.float32)[..., None])
-    store = jnp.bfloat16 if bf16 else jnp.float32
-    big = [x.astype(store) for x in (C, CWg, CWp, emb)]
-    dec = params["dec"]
-    weights = [
-        params["dec0"].reshape(1, hidden).astype(store),
-        dec["wx"].astype(store), dec["wh"].astype(store),
-        dec["b"].reshape(1, -1).astype(jnp.float32),
-        params["glimpse"]["w_q"].astype(store),
-        params["glimpse"]["v"].reshape(hidden, 1).astype(store),
-        params["pointer"]["w_q"].astype(store),
-        params["pointer"]["v"].reshape(hidden, 1).astype(store),
-    ]
-    per_graph_3d = lambda shape: pl.BlockSpec(shape, lambda b: (b, 0, 0))
-    shared = lambda shape: pl.BlockSpec(
-        shape, (lambda b: (0, 0)) if len(shape) == 2 else (lambda b: (0,)))
-    kernel = functools.partial(
-        _decode_kernel, sampled=sampled, mask_infeasible=mask_infeasible)
-    out = pl.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=[
-            per_graph_3d((1, n, hidden)),   # C
-            per_graph_3d((1, n, hidden)),   # CWg
-            per_graph_3d((1, n, hidden)),   # CWp
-            per_graph_3d((1, n, hidden)),   # emb
-            per_graph_3d((1, n, n)),        # padj
-            per_graph_3d((1, n, 1)),        # valid
-            per_graph_3d((1, n, 1)),        # uniforms
-            per_graph_3d((1, 1, hidden)),   # h0
-            per_graph_3d((1, 1, hidden)),   # c0
-            shared((1, hidden)),            # dec0
-            shared((hidden, 4 * hidden)),   # wx
-            shared((hidden, 4 * hidden)),   # wh
-            shared((1, 4 * hidden)),        # b
-            shared((hidden, hidden)),       # w_q glimpse
-            shared((hidden, 1)),            # v glimpse
-            shared((hidden, hidden)),       # w_q pointer
-            shared((hidden, 1)),            # v pointer
-        ],
-        out_specs=[per_graph_3d((1, n, 1))] * 3,
-        out_shape=[jax.ShapeDtypeStruct((B, n, 1), jnp.float32)] * 3,
-        interpret=interpret,
-    )(*big, padj, valid, unif,
-      h0[:, None, :], c0[:, None, :], *weights)
-    order_f, logp, ent = (o[..., 0] for o in out)
-    return order_f.astype(jnp.int32), logp, ent
+    with policy_precision():
+        B, n, hidden = C.shape
+        if sampled and uniforms is None:
+            raise ValueError("sampled decode needs per-step uniforms")
+        CWg, CWp = _ops.precompute_refs(params, C)
+        padj = parent_adjacency(parent_mat, n)
+        valid = (jnp.arange(n)[None, :] < n_valid[:, None]) \
+            .astype(jnp.float32)[..., None]                       # (B, n, 1)
+        unif = (jnp.zeros((B, n, 1), jnp.float32) if uniforms is None
+                else uniforms.astype(jnp.float32)[..., None])
+        store = jnp.bfloat16 if bf16 else jnp.float32
+        big = [x.astype(store) for x in (C, CWg, CWp, emb)]
+        dec = params["dec"]
+        weights = [
+            params["dec0"].reshape(1, hidden).astype(store),
+            dec["wx"].astype(store), dec["wh"].astype(store),
+            dec["b"].reshape(1, -1).astype(jnp.float32),
+            params["glimpse"]["w_q"].astype(store),
+            params["glimpse"]["v"].reshape(hidden, 1).astype(store),
+            params["pointer"]["w_q"].astype(store),
+            params["pointer"]["v"].reshape(hidden, 1).astype(store),
+        ]
+        per_graph_3d = lambda shape: pl.BlockSpec(shape, lambda b: (b, 0, 0))
+        shared = lambda shape: pl.BlockSpec(
+            shape, (lambda b: (0, 0)) if len(shape) == 2 else (lambda b: (0,)))
+        kernel = functools.partial(
+            _decode_kernel, sampled=sampled, mask_infeasible=mask_infeasible)
+        out = pl.pallas_call(
+            kernel,
+            grid=(B,),
+            in_specs=[
+                per_graph_3d((1, n, hidden)),   # C
+                per_graph_3d((1, n, hidden)),   # CWg
+                per_graph_3d((1, n, hidden)),   # CWp
+                per_graph_3d((1, n, hidden)),   # emb
+                per_graph_3d((1, n, n)),        # padj
+                per_graph_3d((1, n, 1)),        # valid
+                per_graph_3d((1, n, 1)),        # uniforms
+                per_graph_3d((1, 1, hidden)),   # h0
+                per_graph_3d((1, 1, hidden)),   # c0
+                shared((1, hidden)),            # dec0
+                shared((hidden, 4 * hidden)),   # wx
+                shared((hidden, 4 * hidden)),   # wh
+                shared((1, 4 * hidden)),        # b
+                shared((hidden, hidden)),       # w_q glimpse
+                shared((hidden, 1)),            # v glimpse
+                shared((hidden, hidden)),       # w_q pointer
+                shared((hidden, 1)),            # v pointer
+            ],
+            out_specs=[per_graph_3d((1, n, 1))] * 3,
+            out_shape=[jax.ShapeDtypeStruct((B, n, 1), jnp.float32)] * 3,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_ops.decode_kernel_vmem_bytes(
+                    n, hidden, sampled=sampled, bf16=bf16)),
+            interpret=interpret,
+        )(*big, padj, valid, unif,
+          h0[:, None, :], c0[:, None, :], *weights)
+        order_f, logp, ent = (o[..., 0] for o in out)
+        return order_f.astype(jnp.int32), logp, ent
 
 
 @functools.partial(
@@ -274,8 +275,10 @@ def decode_pack(params, feats, parent_mat, n_valid, sample_keys=None, *,
     """
     from ...core import ptrnet
     n = feats.shape[1]
-    C, state, emb = jax.vmap(
-        lambda f, nv: ptrnet.encode(params, f, n_valid=nv))(feats, n_valid)
+    with policy_precision():
+        C, state, emb = jax.vmap(
+            lambda f, nv: ptrnet.encode(params, f, n_valid=nv))(
+                feats, n_valid)
     h0, c0 = state
     uniforms = None
     if sampled:

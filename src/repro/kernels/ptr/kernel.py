@@ -16,8 +16,8 @@ story on TPU:
   3 x (n, H) fp32 tiles + weights = ~3 MB at n=782, H=256 (InceptionResNetv2,
   the largest Table-I graph) — comfortably VMEM-resident, MXU-aligned H.
 
-The wrapper pads n up to a lane multiple; padded rows carry mask=False and
-are provably inert (masked to -1e9 before the softmax).
+Callers pad n to a size bucket; padded rows carry mask=False and are
+provably inert (masked to -1e9 before the softmax).
 """
 
 from __future__ import annotations
@@ -28,12 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
-
 __all__ = ["pointer_step_pallas"]
 
 NEG_INF = -1.0e9
@@ -41,27 +35,23 @@ NEG_INF = -1.0e9
 
 def _ptr_kernel(C_ref, CWg_ref, CWp_ref, h_ref, wqg_ref, vg_ref, wqp_ref,
                 vp_ref, mask_ref, out_ref):
-    C = C_ref[0].astype(jnp.float32)          # (n, H)
-    CWg = CWg_ref[0].astype(jnp.float32)
-    CWp = CWp_ref[0].astype(jnp.float32)
-    h = h_ref[0].astype(jnp.float32)          # (1, H) row
-    mask = mask_ref[0]                        # (n,) int32 (1 = selectable)
+    f32 = jnp.float32
+    dot = functools.partial(jnp.dot, preferred_element_type=f32)
+    C = C_ref[0].astype(f32)                  # (n, H)
+    CWg = CWg_ref[0].astype(f32)
+    CWp = CWp_ref[0].astype(f32)
+    h = h_ref[0].astype(f32)                  # (1, H) row
+    sel = mask_ref[0] == 1                    # (n, 1) column
 
-    qg = jax.lax.dot_general(h[None, :], wqg_ref[...].astype(jnp.float32),
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)   # (1, H)
-    sg = jnp.tanh(CWg + qg) @ vg_ref[...].astype(jnp.float32)      # (n,)
-    sg = jnp.where(mask == 1, sg, NEG_INF)
-    m = sg.max()
-    e = jnp.exp(sg - m)
-    attn = e / e.sum()
-    glimpse = attn @ C                                             # (H,)
-    qp = jax.lax.dot_general(glimpse[None, :],
-                             wqp_ref[...].astype(jnp.float32),
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)   # (1, H)
-    logits = jnp.tanh(CWp + qp) @ vp_ref[...].astype(jnp.float32)  # (n,)
-    out_ref[0] = jnp.where(mask == 1, logits, NEG_INF).astype(out_ref.dtype)
+    qg = dot(h, wqg_ref[...].astype(f32))                          # (1, H)
+    sg = dot(jnp.tanh(CWg + qg), vg_ref[...].astype(f32))          # (n, 1)
+    sg = jnp.where(sel, sg, NEG_INF)
+    e = jnp.exp(sg - jnp.max(sg))
+    attn = e / jnp.sum(e)
+    glimpse = jnp.sum(attn * C, axis=0, keepdims=True)             # (1, H)
+    qp = dot(glimpse, wqp_ref[...].astype(f32))                    # (1, H)
+    logits = dot(jnp.tanh(CWp + qp), vp_ref[...].astype(f32))      # (n, 1)
+    out_ref[0] = jnp.where(sel, logits, NEG_INF).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -71,25 +61,31 @@ def pointer_step_pallas(C, CWg, CWp, h, w_q_g, v_g, w_q_p, v_p, mask,
 
     C/CWg/CWp: (B, n, H); h: (B, H); weights shared: (H, H)/(H,);
     mask: (B, n) bool.  Returns logits (B, n) float32.
+
+    Every block's last two dims equal the array's (the TPU tiling rule):
+    ``h`` rides as (B, 1, H) rows, ``mask`` and the logits as (B, n, 1)
+    columns, and the ``v`` heads as (H, 1) so the scores are 2-D dots.
     """
     bsz, n, hidden = C.shape
-    grid = (bsz,)
-    mask_i = mask.astype(jnp.int32)
-    return pl.pallas_call(
+    per_graph = lambda shape: pl.BlockSpec(shape, lambda b: (b, 0, 0))
+    shared = lambda shape: pl.BlockSpec(shape, lambda b: (0, 0))
+    out = pl.pallas_call(
         _ptr_kernel,
-        grid=grid,
+        grid=(bsz,),
         in_specs=[
-            pl.BlockSpec((1, n, hidden), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, n, hidden), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, n, hidden), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, hidden), lambda b: (b, 0)),
-            pl.BlockSpec((hidden, hidden), lambda b: (0, 0)),
-            pl.BlockSpec((hidden,), lambda b: (0,)),
-            pl.BlockSpec((hidden, hidden), lambda b: (0, 0)),
-            pl.BlockSpec((hidden,), lambda b: (0,)),
-            pl.BlockSpec((1, n), lambda b: (b, 0)),
+            per_graph((1, n, hidden)),      # C
+            per_graph((1, n, hidden)),      # CWg
+            per_graph((1, n, hidden)),      # CWp
+            per_graph((1, 1, hidden)),      # h
+            shared((hidden, hidden)),       # w_q glimpse
+            shared((hidden, 1)),            # v glimpse
+            shared((hidden, hidden)),       # w_q pointer
+            shared((hidden, 1)),            # v pointer
+            per_graph((1, n, 1)),           # mask
         ],
-        out_specs=pl.BlockSpec((1, n), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((bsz, n), jnp.float32),
+        out_specs=per_graph((1, n, 1)),
+        out_shape=jax.ShapeDtypeStruct((bsz, n, 1), jnp.float32),
         interpret=interpret,
-    )(C, CWg, CWp, h, w_q_g, v_g, w_q_p, v_p, mask_i)
+    )(C, CWg, CWp, h[:, None, :], w_q_g, v_g.reshape(hidden, 1), w_q_p,
+      v_p.reshape(hidden, 1), mask.astype(jnp.int32)[..., None])
+    return out[..., 0]

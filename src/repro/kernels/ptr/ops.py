@@ -5,16 +5,13 @@ The block specs of both kernels keep each graph's context/projection
 blocks fully VMEM-resident — which is only legal when the block shapes
 land on the TPU vector-register tiling (f32 tiles are 8 sublanes x 128
 lanes) and the per-step working set fits VMEM.  :func:`pointer_shapes_ok`
-/ :func:`decode_kernel_supported` check exactly that; auto-selection
-falls back to the pure-jnp / scan path with a SINGLE warning instead of
-failing mid-compile when a bucket/hidden combo doesn't fit (the old code
-hardcoded the assumption that ``hidden`` is a lane multiple and silently
-broke elsewhere).
+/ :func:`decode_kernel_supported` check exactly that.  A shape they
+refuse raises where a caller asked for the kernel; only the auto choice
+of the serving engine routes such a bucket to the scan, as a choice made
+from the shape before anything compiles.
 """
 
 from __future__ import annotations
-
-import warnings
 
 import jax
 
@@ -27,22 +24,28 @@ __all__ = [
     "make_logits_fn",
     "pointer_shapes_ok",
     "decode_kernel_supported",
+    "decode_kernel_vmem_bytes",
     "make_decode_fn",
 ]
 
 # f32 VREG tiling on TPU: 8 sublanes x 128 lanes
 _SUBLANE = 8
 _LANE = 128
-# leave headroom below the ~16 MB/core VMEM budget for double buffering
-_VMEM_LIMIT_BYTES = 12 << 20
+# VMEM the whole-decode kernel may claim: v5e has 128 MiB of VMEM per
+# TensorCore; the kernel raises its scoped limit (default 16 MiB) to its
+# own footprint estimate, and the gate refuses buckets above this budget.
+_VMEM_LIMIT_BYTES = 96 << 20
 
-_warned: set[str] = set()
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
-def _warn_once(key: str, msg: str) -> None:
-    if key not in _warned:
-        _warned.add(key)
-        warnings.warn(msg, RuntimeWarning, stacklevel=3)
+def _tile_bytes(rows: int, cols: int, itemsize: int = 4) -> int:
+    """VMEM bytes of a (rows, cols) block laid out on (8 x itemsize-packed
+    sublanes) x 128-lane tiles: a column vector costs a full lane tile."""
+    sublanes = _SUBLANE * (4 // itemsize)
+    return _round_up(rows, sublanes) * _round_up(cols, _LANE) * itemsize
 
 
 def pointer_shapes_ok(n: int, hidden: int) -> bool:
@@ -52,26 +55,48 @@ def pointer_shapes_ok(n: int, hidden: int) -> bool:
     return n % _SUBLANE == 0 and hidden % _LANE == 0
 
 
+def decode_kernel_vmem_bytes(bucket_n: int, hidden: int, *,
+                             sampled: bool = False,
+                             bf16: bool = False) -> int:
+    """VMEM footprint of one grid step of the WHOLE-DECODE kernel: every
+    block double-buffered by the pipeline — the four big (n, H) operands
+    (C, the two hoisted projections, emb), the (n, n) parent adjacency,
+    the lane-padded per-node input/output columns and the weights — plus
+    the body's f32 temporaries: two (n, n) copies feeding the feasibility
+    matvec, the upcast operands, the loop-carried columns, and for the
+    sampled pick the (n, n) prefix-sum mask.  At hidden 128 the v5e
+    compiler needs 17.7 MiB (greedy) / 18.7 MiB (sampled) at n = 1024 and
+    78.8 / 82.7 MiB at n = 2048, against 33.6 / 37.6 and 97.6 / 113.6
+    MiB estimated here."""
+    n, h = bucket_n, hidden
+    store = 2 if bf16 else 4
+    col = _tile_bytes(n, 1)
+    blocks = (4 * _tile_bytes(n, h, store)      # C, CWg, CWp, emb
+              + _tile_bytes(n, n)               # parent adjacency
+              + 5 * col                         # valid, uniforms, 3 outputs
+              + 2 * _tile_bytes(1, h)           # h0, c0
+              + _tile_bytes(1, h, store)        # dec0
+              + 2 * _tile_bytes(h, 4 * h, store)  # dec wx, wh
+              + _tile_bytes(1, 4 * h)           # dec bias
+              + 2 * _tile_bytes(h, h, store)    # glimpse/pointer w_q
+              + 2 * _tile_bytes(h, 1, store))   # glimpse/pointer v
+    temps = 2 * _tile_bytes(n, n) + 6 * _tile_bytes(n, h) + 8 * col
+    if sampled:
+        temps += _tile_bytes(n, n)
+    return 2 * blocks + temps
+
+
 def decode_kernel_supported(
         bucket_n: int, hidden: int, *,
         vmem_limit_bytes: int = _VMEM_LIMIT_BYTES) -> bool:
     """True when the WHOLE-DECODE kernel can hold one graph's working set
-    in VMEM at this (bucket, hidden): tiling-aligned blocks plus an f32
-    footprint estimate — 4 big (n, H) operands (C, the two hoisted
-    projections, emb), the (n, n) parent-adjacency, and the decoder/head
-    weights — under the per-core budget."""
+    in VMEM at this (bucket, hidden): tiling-aligned blocks and a
+    :func:`decode_kernel_vmem_bytes` footprint within the budget, taken
+    for the larger (sampled, f32) variant so one answer covers both."""
     if bucket_n % _SUBLANE != 0 or hidden % _LANE != 0:
         return False
-    f32 = 4
-    per_graph = (4 * bucket_n * hidden    # C, CWg, CWp, emb
-                 + bucket_n * bucket_n    # parent adjacency
-                 + 2 * bucket_n           # valid + uniforms columns
-                 + 2 * hidden) * f32      # h0, c0
-    weights = (2 * hidden * 4 * hidden    # dec wx, wh
-               + 4 * hidden               # dec bias
-               + 2 * hidden * hidden      # glimpse/pointer w_q
-               + 3 * hidden) * f32        # v_g, v_p, dec0
-    return per_graph + weights <= vmem_limit_bytes
+    return decode_kernel_vmem_bytes(
+        bucket_n, hidden, sampled=True) <= vmem_limit_bytes
 
 
 def precompute_refs(params, C):
@@ -86,24 +111,19 @@ def precompute_refs(params, C):
 def pointer_step(params, C, CWg, CWp, h, mask, *, impl: str | None = None):
     """One decode step; shapes as in the kernel (batched) or unbatched.
 
-    impl: "pallas" | "interpret" | "ref" (auto: pallas on TPU else ref;
-    auto also requires :func:`pointer_shapes_ok`, warning once and using
-    the reference op when the shape can't tile).
+    impl: "pallas" | "interpret" | "ref" (auto: pallas on TPU else ref).
+    Auto on TPU requires :func:`pointer_shapes_ok` and raises
+    ``ValueError`` on a shape the kernel cannot tile, so a TPU run never
+    leaves the kernel without saying so.
     """
     n, hidden = C.shape[-2], C.shape[-1]
     if impl is None:
-        if jax.default_backend() == "tpu":
-            if pointer_shapes_ok(n, hidden):
-                impl = "pallas"
-            else:
-                _warn_once(
-                    f"ptr-step-{n}-{hidden}",
-                    f"pointer kernel blocks (n={n}, hidden={hidden}) do "
-                    f"not tile to {_SUBLANE}x{_LANE}; using the reference "
-                    "op for this shape")
-                impl = "ref"
-        else:
-            impl = "ref"
+        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+        if impl == "pallas" and not pointer_shapes_ok(n, hidden):
+            raise ValueError(
+                f"pointer kernel blocks (n={n}, hidden={hidden}) do not "
+                f"tile to {_SUBLANE}x{_LANE}; pass impl='ref' to run the "
+                "reference op for this shape")
     g, p = params["glimpse"], params["pointer"]
     unbatched = C.ndim == 2
     if impl == "ref":

@@ -25,7 +25,6 @@ from ..configs import (ARCH_IDS, SHAPES, TrainConfig, get_config,  # noqa: E402
                        shape_applicable)
 from ..models.model import analytic_flops, build_model  # noqa: E402
 from ..utils.hlo import analyze_hlo  # noqa: E402
-from ..utils.jaxcompat import cost_analysis, set_mesh  # noqa: E402
 from . import steps  # noqa: E402
 from .mesh import make_production_mesh  # noqa: E402
 from .roofline import roofline_from_cost  # noqa: E402
@@ -74,7 +73,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     # the reported lower/compile splits negative or skewed, and these flow
     # into checked-in bench artifacts.
     t0 = time.perf_counter()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             tcfg = train_config(arch)
             jfn, (p_sh, o_sh, b_sh), optimizer = steps.make_train_step(
@@ -109,7 +108,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
                                    + mem.output_size_in_bytes
                                    + mem.temp_size_in_bytes),
     }
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     record["xla_cost"] = {"flops": float(ca.get("flops", 0.0)),
                           "bytes": float(ca.get("bytes accessed", 0.0))}
 

@@ -17,16 +17,14 @@ Mesh layout:
 
 from __future__ import annotations
 
-from ..utils.jaxcompat import make_mesh_auto
+import jax
 
 __all__ = ["make_production_mesh", "make_pipeline_mesh", "small_test_mesh"]
 
 
 def _mk(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.37; make_mesh_auto
-    # feature-detects it and omits the kwarg on older JAX (where every
-    # axis is implicitly Auto, so behaviour is identical).
-    return make_mesh_auto(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

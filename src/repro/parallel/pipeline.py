@@ -33,7 +33,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..models import blocks as blocks_mod
-from ..utils.jaxcompat import shard_map
 
 __all__ = ["PipelineRunner"]
 
@@ -103,7 +102,7 @@ class PipelineRunner:
         perm = [(i, i + 1) for i in range(n_stages - 1)]
 
         @functools.partial(
-            shard_map, mesh=self.mesh,
+            jax.shard_map, mesh=self.mesh,
             in_specs=(P("pipe"), P("pipe"), P(None, "data")),
             out_specs=P(None, "data"),
             check_vma=False,

@@ -152,47 +152,19 @@ def constrain(x, logical_axes, mesh: Mesh | None = None, rules=None):
 
 
 def _inside_manual_context() -> bool:
-    # new JAX: the ambient abstract mesh carries Manual axis types
-    try:
-        from jax._src import mesh as mesh_lib
-        am = mesh_lib.get_abstract_mesh()
-        if am is not None and not isinstance(am, tuple) and not am.empty:
-            if any(t == jax.sharding.AxisType.Manual for t in am.axis_types):
-                return True
-    except Exception:  # pragma: no cover
-        pass
-    # JAX 0.4.x: get_abstract_mesh() returns () even inside shard_map;
-    # there, manual regions are exactly where named mesh axes are bound
-    # in the axis env (shard_map/pmap bodies).
-    try:
-        from jax._src import core as core_src
-        return bool(core_src.nonempty_axis_env())
-    except Exception:  # pragma: no cover
-        return False
+    """True inside a shard_map body, whose ambient abstract mesh carries
+    Manual axis types."""
+    am = jax.sharding.get_abstract_mesh()
+    return (not am.empty and any(
+        t == jax.sharding.AxisType.Manual for t in am.axis_types))
 
 
 def _current_mesh() -> Mesh | None:
-    """The active mesh, from either context style: ``jax.set_mesh(mesh)``
-    (new, fills get_concrete_mesh) or ``with mesh:`` (legacy thread
-    resources)."""
-    try:
-        from jax._src import mesh as mesh_lib
-    except Exception:  # pragma: no cover
-        return None
-    # each lookup is independently guarded: on JAX 0.4.x
-    # get_concrete_mesh() returns an empty TUPLE (no .empty attribute),
-    # which must not mask the legacy thread-resources mesh below it.
-    try:
-        mesh = mesh_lib.get_concrete_mesh()
-        if isinstance(mesh, Mesh) and not mesh.empty:
-            return mesh
-    except Exception:  # pragma: no cover
-        pass
-    try:
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:  # pragma: no cover
-        return None
+    """The mesh set by ``jax.set_mesh(mesh)``, also inside ``jax.jit``
+    (where the public ``jax.sharding.get_mesh`` refuses to answer)."""
+    from jax._src import mesh as mesh_lib
+    mesh = mesh_lib.get_concrete_mesh()
+    return None if mesh is None or mesh.empty else mesh
 
 
 def data_parallel_mesh(n_devices: int | None = None,

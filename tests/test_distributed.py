@@ -19,6 +19,7 @@ def run_sub(code: str, timeout=420) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(REPO / "src")
+    env["JAX_PLATFORMS"] = "cpu"       # a child never reaches for the chip
     proc = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                           capture_output=True, text=True, timeout=timeout,
                           env=env)
@@ -33,14 +34,13 @@ def test_sharded_train_step_runs_and_shards():
         from repro.launch import steps
         from repro.launch.mesh import small_test_mesh
         from repro.models.model import build_model
-        from repro.utils.jaxcompat import set_mesh
 
         cfg = get_smoke_config("internlm2-1.8b")
         mesh = small_test_mesh(data=2, model=4)
         model = build_model(cfg, remat=False)
         specs = {"tokens": jax.ShapeDtypeStruct((8, 16), jnp.int32)}
         axes = {"tokens": ("batch", None)}
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jfn, (p_sh, o_sh, b_sh), opt = steps.make_train_step(
                 model, mesh, TrainConfig(microbatches=2), specs, axes)
             params = jax.jit(model.init_params, out_shardings=p_sh)(
@@ -67,7 +67,6 @@ def test_pipeline_matches_sequential():
         from repro.configs import get_smoke_config
         from repro.launch.mesh import make_pipeline_mesh
         from repro.parallel.pipeline import PipelineRunner
-        from repro.utils.jaxcompat import set_mesh
         cfg = get_smoke_config("internlm2-1.8b").scaled(n_layers=6)
         mesh = make_pipeline_mesh(n_stages=4, data=2, model=1)
         runner = PipelineRunner(cfg, mesh, [[0,1],[2],[3,4],[5]], n_micro=4,
@@ -75,7 +74,7 @@ def test_pipeline_matches_sequential():
         params = runner.init_params(jax.random.PRNGKey(0))
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 2, 8, cfg.d_model),
                               jnp.float32).astype(jnp.bfloat16)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_pipe = jax.jit(runner.forward)(params, x)
         y_seq = runner.sequential_forward(params, x)
         err = float(jnp.max(jnp.abs(y_pipe.astype(jnp.float32)
@@ -116,19 +115,18 @@ def test_compressed_psum_matches_mean():
         from jax.sharding import PartitionSpec as P
         from repro.optim import compressed_psum
         from repro.launch.mesh import small_test_mesh
-        from repro.utils.jaxcompat import set_mesh, shard_map
         mesh = small_test_mesh(data=8, model=1)
         x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 64)),
                         jnp.float32)
 
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P("data"), out_specs=P("data"),
                            check_vma=False)
         def f(xs):
             mean, err = compressed_psum({"g": xs}, "data")
             return mean["g"]
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = f(x)
         want = jnp.broadcast_to(x.mean(axis=0, keepdims=True), x.shape)
         rel = float(jnp.max(jnp.abs(got - want)) / (jnp.max(jnp.abs(want)) + 1e-9))
@@ -137,18 +135,18 @@ def test_compressed_psum_matches_mean():
     assert out["rel_err"] < 0.02   # int8 quantization error bound
 
 
-def test_dryrun_entry_single_cell():
+def test_dryrun_entry_single_cell(tmp_path):
     """The dry-run CLI itself works end-to-end for one small cell."""
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src")
+    env["JAX_PLATFORMS"] = "cpu"       # a child never reaches for the chip
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch",
          "internlm2-1.8b", "--shape", "decode_32k", "--mesh", "single",
-         "--outdir", "/tmp/dryrun_test"],
+         "--outdir", str(tmp_path)],
         capture_output=True, text=True, timeout=600, env=env, cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-3000:]
     rec = json.loads(
-        Path("/tmp/dryrun_test/internlm2-1.8b__decode_32k__single.json")
-        .read_text())
+        (tmp_path / "internlm2-1.8b__decode_32k__single.json").read_text())
     assert rec["status"] == "ok"
     assert rec["roofline"]["dominant"] in ("compute", "memory", "collective")

@@ -9,15 +9,16 @@ validation in :mod:`repro.kernels.ptr.ops`:
   apply downstream (uniform-cost graphs, fused kernel vs scan vs host),
 * bf16-path order agreement on the golden Table-I DNN graphs,
 * sampled-path determinism from a fixed key,
-* ``decode_kernel_supported`` / fallback-with-one-warning behaviour.
+* ``decode_kernel_supported`` and the raise on a forced kernel or an
+  untileable pointer step (no quiet fallback to the scan).
 """
 
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import CompGraph, ptrnet, repair, rho, sample_dag
 from repro.core.batching import BucketedDecoder, bucket_for
@@ -165,18 +166,51 @@ def test_decode_kernel_supported_shape_gate():
 
 def test_forced_kernel_on_cpu_falls_back_once_to_scan():
     """decode_impl="kernel" means the compiled TPU kernel; on CPU it
-    must fall back to the scan with a single warning and identical
-    outputs."""
+    raises instead of falling back to the scan, every time it is asked,
+    while the scan and the interpreted kernel still agree."""
     graphs = [sample_dag(np.random.default_rng(3), n=10, deg=3)]
     forced = BucketedDecoder(decode_impl="kernel")
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        o_forced = forced.greedy_orders(_PARAMS, graphs)
-        o_forced2 = forced.greedy_orders(_PARAMS, graphs)
-    fallback = [x for x in w if "fall" in str(x.message).lower()]
-    assert len(fallback) == 1, \
-        f"expected exactly one fallback warning, got {len(fallback)}"
+    for _ in range(2):
+        with pytest.raises(ValueError, match="kernel-interpret"):
+            forced.greedy_orders(_PARAMS, graphs)
     o_scan = BucketedDecoder(decode_impl="scan").greedy_orders(
         _PARAMS, graphs)
-    assert np.array_equal(o_forced[0], o_scan[0])
-    assert np.array_equal(o_forced2[0], o_scan[0])
+    o_interp = BucketedDecoder(decode_impl="kernel-interpret").greedy_orders(
+        _PARAMS, graphs)
+    assert np.array_equal(o_interp[0], o_scan[0])
+
+
+def test_forced_kernel_on_unsupported_shape_raises(monkeypatch):
+    """On TPU a forced kernel on a bucket the gate refuses, or on a
+    heterogeneous system, raises; auto picks the scan for those and the
+    kernel for a good uniform bucket."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    forced = BucketedDecoder(decode_impl="kernel")
+    assert forced._resolve_decode_impl(32, 128) == "kernel"
+    for bucket_n, hidden in ((12, 128), (32, 100), (4096, 128)):
+        with pytest.raises(ValueError, match="do not tile or fit VMEM"):
+            forced._resolve_decode_impl(bucket_n, hidden)
+        assert BucketedDecoder()._resolve_decode_impl(
+            bucket_n, hidden) == "scan"
+    assert BucketedDecoder()._resolve_decode_impl(1024, 128) == "kernel"
+    for impl in ("kernel", "kernel-interpret"):
+        with pytest.raises(ValueError, match="profile-conditioned"):
+            BucketedDecoder(decode_impl=impl)._resolve_decode_impl(
+                32, 128, conditioned=True)
+    assert BucketedDecoder()._resolve_decode_impl(
+        32, 128, conditioned=True) == "scan"
+
+
+def test_untileable_pointer_step_raises_on_tpu(monkeypatch):
+    """Auto ``pointer_step`` on TPU raises on a shape the kernel cannot
+    tile instead of swapping in the reference op."""
+    n, hidden = 12, 32
+    C = jnp.ones((n, hidden), jnp.float32)
+    CWg, CWp = ptr_ops.precompute_refs(_PARAMS, C)
+    h = jnp.ones((hidden,), jnp.float32)
+    mask = jnp.ones((n,), bool)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="do not tile"):
+        ptr_ops.pointer_step(_PARAMS, C, CWg, CWp, h, mask)
+    ref = ptr_ops.pointer_step(_PARAMS, C, CWg, CWp, h, mask, impl="ref")
+    assert ref.shape == (n,)
