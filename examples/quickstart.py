@@ -45,7 +45,7 @@ def main() -> int:
         print("[agent] untrained weights (run examples/train_respect.py "
               "for the trained agent)")
     t0 = time.perf_counter()
-    res = sched.schedule(g, args.stages, sys_, return_timing=True)
+    res = sched.schedule(g, args.stages, sys_)
     t_rl = time.perf_counter() - t0
     assert validate_monotone(g, res.assignment, args.stages)
     ev_rl = evaluate_schedule(g, res.assignment, sys_)

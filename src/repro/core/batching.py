@@ -40,6 +40,7 @@ from collections import OrderedDict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import ptrnet, segment
 from .costmodel import PipelineSystem
@@ -200,21 +201,22 @@ class PaddedGraphBatch:
             return np.concatenate([a, row])
 
         zcat = lambda a: None if a is None else _cat(a, 0)
-        return PaddedGraphBatch(
-            feats=_cat(self.feats, 0),
-            parent_mat=_cat(self.parent_mat, -1),
-            child_mat=_cat(self.child_mat, -1),
-            ancestor_mat=_cat(self.ancestor_mat, False),
-            flops=_cat(self.flops, 0),
-            param_bytes=_cat(self.param_bytes, 0),
-            out_bytes=_cat(self.out_bytes, 0),
-            n_valid=_cat(self.n_valid, 0),
-            label_assign=zcat(self.label_assign),
-            label_order=zcat(self.label_order),
-            exact_assign=zcat(self.exact_assign),
-            exact_bottleneck=zcat(self.exact_bottleneck),
-            dense=False,    # inert rows have n_valid = 0
-        )
+        with TraceAnnotation("respect.pack.pad"):
+            return PaddedGraphBatch(
+                feats=_cat(self.feats, 0),
+                parent_mat=_cat(self.parent_mat, -1),
+                child_mat=_cat(self.child_mat, -1),
+                ancestor_mat=_cat(self.ancestor_mat, False),
+                flops=_cat(self.flops, 0),
+                param_bytes=_cat(self.param_bytes, 0),
+                out_bytes=_cat(self.out_bytes, 0),
+                n_valid=_cat(self.n_valid, 0),
+                label_assign=zcat(self.label_assign),
+                label_order=zcat(self.label_order),
+                exact_assign=zcat(self.exact_assign),
+                exact_bottleneck=zcat(self.exact_bottleneck),
+                dense=False,    # inert rows have n_valid = 0
+            )
 
 
 def _child_width_for(graphs: list[CompGraph],
@@ -268,35 +270,42 @@ def pack_padded(
     if labels is not None:
         la = np.zeros((B, bucket_n), dtype=np.int32)
         lo = np.zeros((B, bucket_n), dtype=np.int32)
-    for i, g in enumerate(graphs):
-        f = embed_graph(g, max_deg)
-        if feats is None:
-            feats = np.zeros((B, bucket_n, f.shape[1]), dtype=np.float32)
-        feats[i, : g.n] = f
-        pmat[i, : g.n] = g.parent_matrix(max_deg)
-        if not decode_only:
-            cmat[i, : g.n] = g.child_matrix(child_width)
-            amat[i, : g.n, : g.n] = g.ancestor_matrix()
-        flops[i, : g.n] = g.flops
-        param_bytes[i, : g.n] = g.param_bytes
-        out_bytes[i, : g.n] = g.out_bytes
-        n_valid[i] = g.n
-        if labels is not None:
-            la[i, : g.n] = labels[0][i]
-            lo[i, : g.n] = labels[1][i]
-    return PaddedGraphBatch(
-        feats=jnp.asarray(feats),
-        parent_mat=jnp.asarray(pmat),
-        child_mat=jnp.asarray(cmat),
-        ancestor_mat=jnp.asarray(amat),
-        flops=jnp.asarray(flops),
-        param_bytes=jnp.asarray(param_bytes),
-        out_bytes=jnp.asarray(out_bytes),
-        n_valid=jnp.asarray(n_valid),
-        label_assign=None if la is None else jnp.asarray(la),
-        label_order=None if lo is None else jnp.asarray(lo),
-        dense=all(g.n == bucket_n for g in graphs),
-    )
+    with TraceAnnotation("respect.pack.embed"):
+        for i, g in enumerate(graphs):
+            f = embed_graph(g, max_deg)
+            if feats is None:
+                feats = np.zeros((B, bucket_n, f.shape[1]), dtype=np.float32)
+            feats[i, : g.n] = f
+            pmat[i, : g.n] = g.parent_matrix(max_deg)
+            if not decode_only:
+                cmat[i, : g.n] = g.child_matrix(child_width)
+            flops[i, : g.n] = g.flops
+            param_bytes[i, : g.n] = g.param_bytes
+            out_bytes[i, : g.n] = g.out_bytes
+            n_valid[i] = g.n
+            if labels is not None:
+                la[i, : g.n] = labels[0][i]
+                lo[i, : g.n] = labels[1][i]
+    # the O(n^2) ancestor closure in a pass of its own, so a trace times
+    # it apart from the embedding
+    if not decode_only:
+        with TraceAnnotation("respect.pack.closure"):
+            for i, g in enumerate(graphs):
+                amat[i, : g.n, : g.n] = g.ancestor_matrix()
+    with TraceAnnotation("respect.pack.h2d"):
+        return PaddedGraphBatch(
+            feats=jnp.asarray(feats),
+            parent_mat=jnp.asarray(pmat),
+            child_mat=jnp.asarray(cmat),
+            ancestor_mat=jnp.asarray(amat),
+            flops=jnp.asarray(flops),
+            param_bytes=jnp.asarray(param_bytes),
+            out_bytes=jnp.asarray(out_bytes),
+            n_valid=jnp.asarray(n_valid),
+            label_assign=None if la is None else jnp.asarray(la),
+            label_order=None if lo is None else jnp.asarray(lo),
+            dense=all(g.n == bucket_n for g in graphs),
+        )
 
 
 class _LRU:
@@ -312,6 +321,8 @@ class _LRU:
         self.maxsize = maxsize
         self._d: OrderedDict = OrderedDict()
         self._lock = threading.Lock()
+        self.inserted = 0      # puts, each a program built
+        self.evicted = 0
 
     def get(self, key):
         with self._lock:
@@ -324,8 +335,10 @@ class _LRU:
         with self._lock:
             self._d[key] = value
             self._d.move_to_end(key)
+            self.inserted += 1
             while len(self._d) > self.maxsize:
                 self._d.popitem(last=False)
+                self.evicted += 1
 
     def keys(self) -> list:
         with self._lock:
@@ -532,16 +545,29 @@ class BucketedDecoder:
     def compiled_shapes(self) -> list[tuple]:
         return [k[1:] for k in self._fns.keys()]
 
+    @property
+    def programs_built(self) -> int:
+        """Programs put in the LRU: each compiles on its first call."""
+        return self._fns.inserted
+
+    @property
+    def programs_evicted(self) -> int:
+        """Programs the LRU dropped; a later call of that shape rebuilds."""
+        return self._fns.evicted
+
     # ------------------------------------------------------------------ #
     def _packed_buckets(self, graphs: list[CompGraph],
                         decode_only: bool = False):
         """Yield (bucket_n, idxs, batch) with both dims padded to buckets."""
         for bucket_n, idxs in bucketize(graphs, self.min_bucket).items():
-            batch = pack_padded(
-                [graphs[i] for i in idxs], bucket_n, self.max_deg,
-                decode_only=decode_only)
-            bucket_b = 1 << (batch.batch - 1).bit_length()
-            yield bucket_n, idxs, batch.pad_batch(bucket_b)
+            with TraceAnnotation("respect.pack", bucket_n=bucket_n,
+                                 batch=len(idxs)):
+                batch = pack_padded(
+                    [graphs[i] for i in idxs], bucket_n, self.max_deg,
+                    decode_only=decode_only)
+                bucket_b = 1 << (batch.batch - 1).bit_length()
+                batch = batch.pad_batch(bucket_b)
+            yield bucket_n, idxs, batch
 
     def greedy_orders(self, params, graphs: list[CompGraph]) -> list[np.ndarray]:
         """Decode every graph; returns per-graph orders (length ``g.n``).
@@ -583,13 +609,20 @@ class BucketedDecoder:
         for _, idxs, batch in self._packed_buckets(graphs):
             impl = self._resolve_decode_impl(batch.bucket_n, hidden,
                                              conditioned=conditioned)
+            built = self.programs_built
             fn = self._fused_fn(batch.bucket_n, batch.batch,
                                 batch.child_width, n_stages, system, impl)
-            orders, assigns = fn(params, batch)
-            orders = np.asarray(orders)
-            assigns = np.asarray(assigns)
-            for row, i in enumerate(idxs):
-                n = graphs[i].n
-                results[i] = (orders[row, :n].astype(np.int64),
-                              assigns[row, :n].astype(np.int64))
+            with TraceAnnotation("respect.run", bucket_n=batch.bucket_n,
+                                 bucket_b=batch.batch, impl=impl,
+                                 new_program=self.programs_built != built):
+                with TraceAnnotation("respect.dispatch"):
+                    orders, assigns = fn(params, batch)
+                with TraceAnnotation("respect.fetch"):
+                    orders = np.asarray(orders)
+                    assigns = np.asarray(assigns)
+                with TraceAnnotation("respect.unpack"):
+                    for row, i in enumerate(idxs):
+                        n = graphs[i].n
+                        results[i] = (orders[row, :n].astype(np.int64),
+                                      assigns[row, :n].astype(np.int64))
         return results

@@ -106,6 +106,7 @@ def _lstm_step(p, x, state):
     return h, c
 
 
+@jax.named_scope("encode")
 def encode(params, feats, n_valid=None, unroll: int = 1):
     """feats (n, F) -> contexts C (n, H), final (h, c), projected emb (n, H).
 
@@ -200,6 +201,7 @@ def inverse_cdf_pick(probs, u):
     return jnp.where(total > draw, idx, last_live)
 
 
+@jax.named_scope("decode")
 def decode(
     params,
     C,

@@ -32,13 +32,13 @@ by ``examples/train_respect.py`` ships with the benchmarks.
 from __future__ import annotations
 
 import threading
-import time
 from collections import OrderedDict
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import ptrnet
 from .batching import BucketedDecoder
@@ -162,19 +162,13 @@ class RespectScheduler:
         graph: CompGraph,
         n_stages: int,
         system: PipelineSystem | None = None,
-        return_timing: bool = False,
         use_cache: bool = True,
     ) -> ScheduleResult:
         """Schedule one graph: a batch-of-one through the serving engine,
         sharing the fused per-bucket programs AND the content-hash LRU
         schedule cache with :meth:`schedule_many`."""
-        t0 = time.perf_counter()
-        res = self.schedule_many(
-            [graph], n_stages, system,
-            return_timing=return_timing, use_cache=use_cache)[0]
-        if return_timing:
-            res["t_total_s"] = time.perf_counter() - t0
-        return res
+        return self.schedule_many([graph], n_stages, system,
+                                  use_cache=use_cache)[0]
 
     def schedule_model(
         self,
@@ -267,13 +261,18 @@ class RespectScheduler:
             self.cache_misses = 0
 
     def cache_stats(self) -> dict:
-        """Consistent snapshot of the cache counters (one lock hold)."""
+        """Snapshot of the schedule-cache counters (one lock hold), and of
+        the fused programs the decoder built and evicted: a built program
+        compiles on its first call, so a rise in a window is a recompile."""
         with self._cache_lock:
-            return {
+            stats = {
                 "hits": self.cache_hits,
                 "misses": self.cache_misses,
                 "size": len(self._cache),
             }
+        stats["programs_built"] = self._decoder.programs_built
+        stats["programs_evicted"] = self._decoder.programs_evicted
+        return stats
 
     def _result_from(self, entry: dict, n_stages: int, model: str,
                      cache_hit: bool) -> ScheduleResult:
@@ -294,7 +293,6 @@ class RespectScheduler:
         graphs: list[CompGraph],
         n_stages: int,
         system: PipelineSystem | None = None,
-        return_timing: bool = False,
         use_cache: bool = True,
     ) -> list[ScheduleResult]:
         """Schedule a batch of graphs through the fused bucketed engine.
@@ -305,50 +303,45 @@ class RespectScheduler:
         across calls — are served from an LRU schedule cache.
         """
         system = (system or PipelineSystem(n_stages)).with_stages(n_stages)
-        t0 = time.perf_counter()
         results: list[ScheduleResult | None] = [None] * len(graphs)
         misses: list[int] = []
         seen: dict[tuple, list[int]] = {}   # key -> positions awaiting fill
-        # content hashing is pure per-graph work — keep it outside the lock
-        keys = ([self._cache_key(g, n_stages, system) for g in graphs]
-                if use_cache else [None] * len(graphs))
         # cache entries are immutable once inserted (the cache owns them;
         # results are always fresh copies), so the lock only needs to
         # cover the dict operations — entry refs are snapshotted under
         # the lock and the numpy copies happen outside it.
         hit_fills: list[tuple[int, dict]] = []
-        with self._cache_lock:
-            for i in range(len(graphs)):
-                key = keys[i]
-                if use_cache and key in self._cache:
-                    self._cache.move_to_end(key)
-                    self.cache_hits += 1
-                    hit_fills.append((i, self._cache[key]))
-                elif use_cache and key in seen:
-                    seen[key].append(i)     # duplicate within this batch
-                else:
-                    if use_cache:
-                        seen[key] = [i]
-                    misses.append(i)
-        for i, entry in hit_fills:
-            results[i] = self._result_from(
-                entry, n_stages, graphs[i].model_name, cache_hit=True)
+        with TraceAnnotation("respect.lookup"):
+            # content hashing is pure per-graph work — outside the lock
+            keys = ([self._cache_key(g, n_stages, system) for g in graphs]
+                    if use_cache else [None] * len(graphs))
+            with self._cache_lock:
+                for i in range(len(graphs)):
+                    key = keys[i]
+                    if use_cache and key in self._cache:
+                        self._cache.move_to_end(key)
+                        self.cache_hits += 1
+                        hit_fills.append((i, self._cache[key]))
+                    elif use_cache and key in seen:
+                        seen[key].append(i)     # duplicate within this batch
+                    else:
+                        if use_cache:
+                            seen[key] = [i]
+                        misses.append(i)
 
-        t_fused = 0.0
+        entries: dict[int, dict] = {}
         if misses:
             # device compute runs UNLOCKED — concurrent callers missing on
             # different graphs overlap here; two callers racing on the SAME
             # graph both compute (deterministically identical) entries and
             # the second insert below harmlessly replaces the first.
-            td = time.perf_counter()
             fused = self._decoder.fused_schedules(
                 self.params, [graphs[i] for i in misses], n_stages, system)
-            t_fused = time.perf_counter() - td
             entries = {i: {"assignment": assignment, "order": order}
                        for i, (order, assignment) in zip(misses, fused)}
-            dup_fills: list[tuple[int, dict]] = []
-            with self._cache_lock:
-                if use_cache:
+        with TraceAnnotation("respect.results"):
+            if entries and use_cache:
+                with self._cache_lock:
                     # counters track cache LOOKUPS: hits + misses == the
                     # number of cached-path requests.  use_cache=False
                     # traffic (warmup, benchmarks) never consults the
@@ -362,19 +355,13 @@ class RespectScheduler:
                         self._cache[keys[i]] = entry
                         for j in seen.get(keys[i], [])[1:]:
                             self.cache_hits += 1
-                            dup_fills.append((j, entry))
+                            hit_fills.append((j, entry))
                         while len(self._cache) > self._cache_size:
                             self._cache.popitem(last=False)
             for i, entry in entries.items():
                 results[i] = self._result_from(
                     entry, n_stages, graphs[i].model_name, cache_hit=False)
-            for j, entry in dup_fills:
+            for j, entry in hit_fills:
                 results[j] = self._result_from(
                     entry, n_stages, graphs[j].model_name, cache_hit=True)
-
-        if return_timing:
-            t_total = time.perf_counter() - t0
-            for r in results:
-                r["t_fused_batch_s"] = t_fused
-                r["t_total_batch_s"] = t_total
         return results

@@ -90,6 +90,7 @@ def exact_dp_batch(flops, param_bytes, out_bytes, parent_mat,
     return jax.vmap(one)(flops, param_bytes, out_bytes, parent_mat, n_valid)
 
 
+@jax.named_scope("rho_dp")
 def rho_dp_jax(
     order,
     flops,
@@ -308,6 +309,7 @@ def co_consumer_repair_jax(parent_mat, child_mat, assign,
     return out
 
 
+@jax.named_scope("repair")
 def repair_jax(parent_mat, child_mat, anc_mat, assign, n_stages: int,
                max_iters: int = 8, enforce_co_consumer: bool = True,
                param_bytes=None, mem_capacity=None):

@@ -54,8 +54,10 @@ import queue
 import threading
 import time
 from concurrent.futures import Future
+from functools import partial
 
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from ..core.costmodel import PipelineSystem
 from ..core.graph import CompGraph, InvalidGraphError, validate_graph
@@ -179,6 +181,9 @@ class SchedulerService:
         # requests the worker currently holds (crash scope); worker-thread
         # only — the supervisor runs in the same thread after a crash
         self._inhand: list[_Request] = []
+        # when the current flush started: results stamp their queue time
+        # (``queued_s``) from it; worker-thread only, like ``_inhand``
+        self._flush_t0 = time.perf_counter()
         # counters (all mutated under self._lock)
         self._requests = 0
         self._completed = 0
@@ -210,6 +215,7 @@ class SchedulerService:
     # ------------------------------------------------------------------ #
     # client API
     # ------------------------------------------------------------------ #
+    @partial(annotate_function, name="respect.submit")
     def submit(self, graph: CompGraph, n_stages: int,
                system: PipelineSystem | None = None,
                timeout: float | None = None,
@@ -433,7 +439,8 @@ class SchedulerService:
             with self._lock:
                 closed = self._closed
             try:
-                item = self._queue.get(timeout=0.05)
+                with TraceAnnotation("respect.wait_request"):
+                    item = self._queue.get(timeout=0.05)
             except queue.Empty:
                 if closed and self._drain():
                     return
@@ -465,6 +472,7 @@ class SchedulerService:
             if not leftovers:
                 time.sleep(1e-3)
 
+    @partial(annotate_function, name="respect.collect")
     def _collect(self, first: _Request):
         """Fill a micro-batch: up to ``max_batch`` requests, waiting at
         most ``max_wait_s`` past the moment the batch opened.  A backlog
@@ -486,36 +494,38 @@ class SchedulerService:
     def _flush(self, batch: list[_Request], reason: str) -> None:
         if not batch:
             return
-        with self._lock:
-            self._batches += 1
-            self._max_batch_observed = max(self._max_batch_observed,
-                                           len(batch))
-            if reason == "full":
-                self._flush_full += 1
-            elif reason == "deadline":
-                self._flush_deadline += 1
-            else:
-                self._flush_drain += 1
-        # sustained-overload check, once per flush: queue depth past the
-        # batch we just scooped, plus (optionally) rolling p99
-        overloaded = False
-        if self._degrade is not None:
-            p99 = None
-            if self._degrade.p99_high_ms is not None:
-                p99 = self._latency.percentiles_ms((99.0,))[0]
-            overloaded = self._overload.update(self._queue.qsize(), p99)
-        # one schedule_many per (stages, system) group; size bucketing
-        # happens inside the engine.  _inhand is the crash scope: if
-        # anything below escapes, the supervisor resolves what's left.
-        self._inhand = list(batch)
-        groups: dict[tuple, list[_Request]] = {}
-        for r in batch:
-            groups.setdefault((r.n_stages, r.system), []).append(r)
-        for (n_stages, system), reqs in groups.items():
-            self._serve_group(reqs, n_stages, system, overloaded)
-        self._inhand = []
-        # a fully clean flush re-arms the supervisor's backoff
-        self._restart_backoff = self._restart_backoff_init
+        self._flush_t0 = time.perf_counter()
+        with TraceAnnotation("respect.flush", size=len(batch), reason=reason):
+            with self._lock:
+                self._batches += 1
+                self._max_batch_observed = max(self._max_batch_observed,
+                                               len(batch))
+                if reason == "full":
+                    self._flush_full += 1
+                elif reason == "deadline":
+                    self._flush_deadline += 1
+                else:
+                    self._flush_drain += 1
+            # sustained-overload check, once per flush: queue depth past the
+            # batch we just scooped, plus (optionally) rolling p99
+            overloaded = False
+            if self._degrade is not None:
+                p99 = None
+                if self._degrade.p99_high_ms is not None:
+                    p99 = self._latency.percentiles_ms((99.0,))[0]
+                overloaded = self._overload.update(self._queue.qsize(), p99)
+            # one schedule_many per (stages, system) group; size bucketing
+            # happens inside the engine.  _inhand is the crash scope: if
+            # anything below escapes, the supervisor resolves what's left.
+            self._inhand = list(batch)
+            groups: dict[tuple, list[_Request]] = {}
+            for r in batch:
+                groups.setdefault((r.n_stages, r.system), []).append(r)
+            for (n_stages, system), reqs in groups.items():
+                self._serve_group(reqs, n_stages, system, overloaded)
+            self._inhand = []
+            # a fully clean flush re-arms the supervisor's backoff
+            self._restart_backoff = self._restart_backoff_init
 
     # ------------------------------------------------------------------ #
     # the ladder
@@ -690,13 +700,17 @@ class SchedulerService:
             return
         fut.set_exception(exc)
 
+    @partial(annotate_function, name="respect.resolve")
     def _resolve(self, reqs: list[_Request], results: list[ScheduleResult],
                  reason: str | None = None) -> None:
+        """Complete each request and its coalesced duplicates; the
+        futures' done callbacks run here, on the worker thread."""
         t_done = time.perf_counter()
         for req, res in zip(reqs, results):
             rung = res.get("served_by", RUNG_POLICY)
             met = req.deadline is None or t_done <= req.deadline
             res["deadline_met"] = met
+            res["queued_s"] = max(0.0, self._flush_t0 - req.t_submit)
             with self._lock:
                 waiters = self._detach(req)
                 self._completed += 1 + len(waiters)
@@ -725,12 +739,15 @@ class SchedulerService:
                 wres = _copied_result(res)
                 wmet = dl is None or t_done <= dl
                 wres["deadline_met"] = wmet
+                # a duplicate that joined after the flush began waited 0
+                wres["queued_s"] = max(0.0, self._flush_t0 - t_sub)
                 if not wmet:
                     with self._lock:
                         self._deadline_missed += 1
                 self._latency.add(t_done - t_sub)
                 self._set_result(fut, wres)
 
+    @partial(annotate_function, name="respect.resolve")
     def _resolve_error(self, reqs: list[_Request], exc: Exception) -> None:
         for req in reqs:
             with self._lock:

@@ -465,11 +465,15 @@ def test_warmup_precompiles_expected_bucket_shapes(pool):
         assert any(k[0] == 16 and k[1] == 2 for k in fused)
         assert any(k[0] == 16 and k[1] == 1 for k in fused)
         # warmup must not pollute the schedule cache
-        assert s.cache_stats() == {"hits": 0, "misses": 0, "size": 0}
+        stats = s.cache_stats()
+        assert stats == {"hits": 0, "misses": 0, "size": 0,
+                         "programs_built": len(shapes),
+                         "programs_evicted": 0}
         # a live request of a warmed shape compiles nothing new
         n_before = len(shapes)
         svc.submit(pool[0], N_STAGES).result(timeout=60)
         assert len(s._decoder.compiled_shapes) == n_before
+        assert s.cache_stats()["programs_built"] == n_before
     finally:
         svc.close()
 
