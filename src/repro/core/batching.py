@@ -10,9 +10,10 @@ the WHOLE miss pipeline on device:
 * **size bucketing** — a graph with ``n`` nodes is padded up to the next
   power-of-two bucket (``bucket_for``), so arbitrary request mixes compile
   at most ``log2(n_max)`` programs instead of one per distinct size;
-* **padded packing** — :func:`pack_padded` stacks embeddings, parent/child
-  matrices and the three cost attributes into a :class:`PaddedGraphBatch`
-  carrying ``n_valid`` per graph; the pad-aware decode
+* **padded packing** — :func:`pack_padded` builds embeddings, parent/child
+  matrices and the three cost attributes of a whole bucket with array
+  operations into a :class:`PaddedGraphBatch` carrying ``n_valid`` per
+  graph; the pad-aware decode
   (:mod:`repro.core.ptrnet`) and the ``n_valid``-aware segmentation DP
   (:mod:`repro.core.segment`) guarantee the valid prefix matches the
   unpadded pipeline bit-for-bit;
@@ -44,7 +45,7 @@ from jax.profiler import TraceAnnotation
 
 from . import ptrnet, segment
 from .costmodel import PipelineSystem
-from .embedding import embed_graph
+from .embedding import embed_rows, flat_parents, node_slots, op_id_block
 from .graph import CompGraph
 
 __all__ = [
@@ -52,6 +53,7 @@ __all__ = [
     "bucketize",
     "PaddedGraphBatch",
     "pack_padded",
+    "pack_path",
     "BucketedDecoder",
     "DECODE_IMPLS",
     "DECODE_IMPL_ENV",
@@ -60,6 +62,10 @@ __all__ = [
 
 MIN_BUCKET = 8
 MIN_CHILD_WIDTH = 4
+
+#: ``pack_path``'s crossover: the smallest batch that takes the batched
+#: levels/closure recurrence (buckets above 256 ask for bucket_n / 32)
+PACK_BATCHED_MIN = 8
 
 #: scan-path unroll factor for the serving decode programs: identical
 #: per-step math (orders are bit-identical), but unrolling cuts the CPU
@@ -219,12 +225,111 @@ class PaddedGraphBatch:
             )
 
 
-def _child_width_for(graphs: list[CompGraph],
+def _child_width_for(max_out_degree: int,
                      min_width: int = MIN_CHILD_WIDTH) -> int:
-    """Power-of-two child-matrix width covering every graph's out-degree
+    """Power-of-two child-matrix width covering the largest out-degree
     (with a floor, so batches with different fan-outs share programs)."""
-    mc = max((g.max_out_degree for g in graphs), default=1)
-    return max(min_width, 1 << (max(mc, 1) - 1).bit_length())
+    return max(min_width, 1 << (max(max_out_degree, 1) - 1).bit_length())
+
+
+def _parent_rows(parent_mat: np.ndarray) -> np.ndarray:
+    """(N, D, B) flat row ``p * B + b`` of each parent slot in an
+    ``(N + 1, B, ...)`` node-major block whose row N is the fill that the
+    -1 slots read."""
+    B, N, _ = parent_mat.shape
+    p = np.where(parent_mat >= 0, parent_mat, N).astype(np.int64)
+    return np.ascontiguousarray(
+        (p * B + np.arange(B)[:, None, None]).transpose(1, 2, 0))
+
+
+def _levels_batched(graphs, parent_mat, slots):
+    """ASAP levels by one recurrence over the node index, across the whole
+    batch: parents precede children, so row v reads only rows < v."""
+    B, N, _ = parent_mat.shape
+    rows = _parent_rows(parent_mat)
+    lv = np.full((N + 1, B), -1, dtype=np.int64)
+    flat = lv.reshape(-1)
+    for v in range(N):
+        lv[v] = flat.take(rows[v]).max(axis=0) + 1
+    return lv[:N].T
+
+
+def _closure_batched(graphs, parent_mat, slots):
+    """Ancestor closure by the same recurrence: row v is its parents' rows
+    or-ed, plus v itself."""
+    B, N, _ = parent_mat.shape
+    rows = _parent_rows(parent_mat)
+    valid = np.zeros(B * N, dtype=bool)
+    valid[slots] = True
+    valid = valid.reshape(B, N)
+    anc = np.zeros((N + 1, B, N), dtype=bool)
+    flat = anc.reshape(-1, N)
+    for v in range(N):
+        row = np.logical_or.reduce(flat.take(rows[v], axis=0), axis=0)
+        row[:, v] = valid[:, v]
+        anc[v] = row
+    return np.ascontiguousarray(anc[:N].transpose(1, 0, 2))
+
+
+def _levels_per_graph(graphs, parent_mat, slots):
+    """ASAP levels graph by graph (:func:`repro.core.graph.asap_levels`)."""
+    lv = np.zeros(parent_mat.shape[:2], dtype=np.int64)
+    lv.reshape(-1)[slots] = np.concatenate([g.levels for g in graphs])
+    return lv
+
+
+def _closure_per_graph(graphs, parent_mat, slots):
+    """Ancestor closure graph by graph, edge by edge
+    (:meth:`CompGraph.ancestor_matrix`)."""
+    B, N, _ = parent_mat.shape
+    amat = np.zeros((B, N, N), dtype=bool)
+    for i, g in enumerate(graphs):
+        amat[i, : g.n, : g.n] = g.ancestor_matrix()
+    return amat
+
+
+#: how a pack computes its levels and ancestor closure (see pack_path)
+PACK_PATHS = {
+    "batched": (_levels_batched, _closure_batched),
+    "per_graph": (_levels_per_graph, _closure_per_graph),
+}
+
+
+def pack_path(bucket_n: int, batch: int) -> str:
+    """The levels/closure path for a ``(bucket_n, batch)`` pack.
+
+    The batched recurrence takes ``bucket_n`` array steps whatever the
+    batch, each dearer as batch x bucket grows; the per-graph loops take a
+    Python step per node and edge of every graph.  On the host the
+    recurrence wins from about 8 graphs a bucket up to bucket 256 and from
+    ``bucket_n / 32`` graphs above it.  Both paths give the same arrays."""
+    return ("batched" if batch >= max(PACK_BATCHED_MIN, bucket_n // 32)
+            else "per_graph")
+
+
+def _fill_children(child_mat: np.ndarray, child: np.ndarray,
+                   parent: np.ndarray) -> None:
+    """Scatter each edge into its parent's row of ``child_mat``, children
+    in ascending order (the order the device repair walks them); an
+    out-degree above the width raises :meth:`CompGraph.child_matrix`'s
+    ``ValueError``."""
+    _, N, width = child_mat.shape
+    order = np.argsort(parent, kind="stable")
+    keys = parent[order]
+    rank = np.arange(len(keys)) - np.searchsorted(keys, keys)
+    over = np.flatnonzero(rank >= width)
+    if len(over):
+        u = keys[over[0]]
+        raise ValueError(f"node {u % N} has out-degree "
+                         f"{np.count_nonzero(keys == u)} > {width}")
+    child_mat.reshape(-1, width)[keys, rank] = child[order] % N
+
+
+def _node_block(values, shape, slots, dtype) -> np.ndarray:
+    """Per-graph node arrays scattered into one zero-padded block."""
+    out = np.zeros(shape, dtype=dtype)
+    out.reshape(-1)[slots] = np.concatenate(values)
+    return out
 
 
 def pack_padded(
@@ -246,7 +351,11 @@ def pack_padded(
     ``labels`` (optional) is the ``(assigns, orders)`` pair from
     :func:`repro.core.rl.label_graphs` — per-graph arrays of length ``g.n``
     that are zero padded into the batch's ``label_assign``/``label_order``
-    fields, turning the serving pack into a training pack."""
+    fields, turning the serving pack into a training pack.
+
+    The whole batch is built with array operations over flat per-node and
+    per-edge arrays; only the levels and the ancestor closure depend on
+    :func:`pack_path`, and both paths give the same arrays."""
     if not graphs:
         raise ValueError("empty graph list")
     n_max = max(g.n for g in graphs)
@@ -254,44 +363,39 @@ def pack_padded(
         bucket_n = bucket_for(n_max, min_bucket)
     if n_max > bucket_n:
         raise ValueError(f"graph with {n_max} nodes exceeds bucket {bucket_n}")
-    if child_width is None:
-        child_width = 0 if decode_only else _child_width_for(graphs)
     B = len(graphs)
-    feats = None
-    pmat = np.full((B, bucket_n, max_deg), -1, dtype=np.int32)
-    cmat = np.full((B, bucket_n, child_width), -1, dtype=np.int32)
-    anc_n = 0 if decode_only else bucket_n
-    amat = np.zeros((B, anc_n, anc_n), dtype=bool)
-    flops = np.zeros((B, bucket_n), dtype=np.float32)
-    param_bytes = np.zeros((B, bucket_n), dtype=np.float32)
-    out_bytes = np.zeros((B, bucket_n), dtype=np.float32)
-    n_valid = np.zeros(B, dtype=np.int32)
+    levels_of, closure_of = PACK_PATHS[pack_path(bucket_n, B)]
+    ns = np.fromiter((g.n for g in graphs), np.int64, B)
+    slots = node_slots(ns, bucket_n)
+    shape = (B, bucket_n)
     la = lo = None
-    if labels is not None:
-        la = np.zeros((B, bucket_n), dtype=np.int32)
-        lo = np.zeros((B, bucket_n), dtype=np.int32)
     with TraceAnnotation("respect.pack.embed"):
-        for i, g in enumerate(graphs):
-            f = embed_graph(g, max_deg)
-            if feats is None:
-                feats = np.zeros((B, bucket_n, f.shape[1]), dtype=np.float32)
-            feats[i, : g.n] = f
-            pmat[i, : g.n] = g.parent_matrix(max_deg)
-            if not decode_only:
-                cmat[i, : g.n] = g.child_matrix(child_width)
-            flops[i, : g.n] = g.flops
-            param_bytes[i, : g.n] = g.param_bytes
-            out_bytes[i, : g.n] = g.out_bytes
-            n_valid[i] = g.n
-            if labels is not None:
-                la[i, : g.n] = labels[0][i]
-                lo[i, : g.n] = labels[1][i]
+        pmat, child, parent = flat_parents(graphs, slots, bucket_n, max_deg)
+        if child_width is None:
+            child_width = 0 if decode_only else _child_width_for(
+                int(np.bincount(parent).max(initial=0)))
+        cmat = np.full((B, bucket_n, child_width), -1, dtype=np.int32)
+        if not decode_only:
+            _fill_children(cmat, child, parent)
+        flops, param_bytes, out_bytes = (
+            _node_block([getattr(g, a) for g in graphs], shape, slots,
+                        np.float32)
+            for a in ("flops", "param_bytes", "out_bytes"))
+        mem = _node_block([g.param_bytes + g.out_bytes for g in graphs],
+                          shape, slots, np.float64)
+        feats = embed_rows(levels_of(graphs, pmat, slots),
+                           op_id_block(graphs, bucket_n, slots), mem, pmat,
+                           ns)
+        if labels is not None:
+            la = _node_block(labels[0], shape, slots, np.int32)
+            lo = _node_block(labels[1], shape, slots, np.int32)
     # the O(n^2) ancestor closure in a pass of its own, so a trace times
     # it apart from the embedding
-    if not decode_only:
+    if decode_only:
+        amat = np.zeros((B, 0, 0), dtype=bool)
+    else:
         with TraceAnnotation("respect.pack.closure"):
-            for i, g in enumerate(graphs):
-                amat[i, : g.n, : g.n] = g.ancestor_matrix()
+            amat = closure_of(graphs, pmat, slots)
     with TraceAnnotation("respect.pack.h2d"):
         return PaddedGraphBatch(
             feats=jnp.asarray(feats),
@@ -301,10 +405,10 @@ def pack_padded(
             flops=jnp.asarray(flops),
             param_bytes=jnp.asarray(param_bytes),
             out_bytes=jnp.asarray(out_bytes),
-            n_valid=jnp.asarray(n_valid),
+            n_valid=jnp.asarray(ns.astype(np.int32)),
             label_assign=None if la is None else jnp.asarray(la),
             label_order=None if lo is None else jnp.asarray(lo),
-            dense=all(g.n == bucket_n for g in graphs),
+            dense=bool((ns == bucket_n).all()),
         )
 
 
@@ -561,7 +665,8 @@ class BucketedDecoder:
         """Yield (bucket_n, idxs, batch) with both dims padded to buckets."""
         for bucket_n, idxs in bucketize(graphs, self.min_bucket).items():
             with TraceAnnotation("respect.pack", bucket_n=bucket_n,
-                                 batch=len(idxs)):
+                                 batch=len(idxs),
+                                 path=pack_path(bucket_n, len(idxs))):
                 batch = pack_padded(
                     [graphs[i] for i in idxs], bucket_n, self.max_deg,
                     decode_only=decode_only)

@@ -18,6 +18,7 @@ same data as fixed-shape jnp arrays for the jitted RL path.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 
 import numpy as np
@@ -42,9 +43,11 @@ class InvalidGraphError(ValueError):
     (e.g. ``parents`` mutated after construction)."""
 
 
+@functools.lru_cache(maxsize=1 << 16)
 def hash_op_name(name: str, modulus: int = 1 << 16) -> int:
     """Stable integer id for an operator name (paper: "node IDs are unique
-    integers generated by hashing all the operator names")."""
+    integers generated by hashing all the operator names").  Memoised: a
+    pure function of its arguments, and served graphs repeat their names."""
     digest = hashlib.sha256(name.encode("utf-8")).digest()
     return int.from_bytes(digest[:4], "little") % modulus
 
@@ -208,9 +211,6 @@ class CompGraph:
             for u in ps:
                 m[v] |= m[u]
         return m
-
-    def op_ids(self, modulus: int = 1 << 16) -> np.ndarray:
-        return np.array([hash_op_name(nm, modulus) for nm in self.names], np.int64)
 
     def content_hash(self) -> str:
         """Stable digest of everything a scheduler's output depends on:

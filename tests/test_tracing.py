@@ -51,11 +51,13 @@ def test_service_spans_nest_on_the_worker_line(tmp_path):
     rng = np.random.default_rng(0)
     graphs = [sample_dag(rng, n=10) for _ in range(3)]
     sched.schedule_many(graphs[:1], N_STAGES, use_cache=False)   # compile
+    wide = [sample_dag(rng, n=30) for _ in range(16)]
     with jax.profiler.trace(str(tmp_path)):
         with SchedulerService(sched, max_batch=3, max_wait_ms=5e3) as svc:
             futs = [svc.submit(g, N_STAGES) for g in graphs]
             for f in futs:
                 f.result(timeout=60)
+        list(BucketedDecoder()._packed_buckets(wide))   # pack only
     (path,) = tmp_path.glob("**/*.xplane.pb")
     lines = _program_lines(str(path))
     worker = next(k for k, evs in lines.items()
@@ -86,6 +88,11 @@ def test_service_spans_nest_on_the_worker_line(tmp_path):
             assert p[1] <= k[1] and k[2] <= p[2]
     (pack,) = [e for e in evs if e[0] == "respect.pack"]
     assert (pack[3]["bucket_n"], pack[3]["batch"]) == (16, 3)
+    assert pack[3]["path"] == "per_graph"
+    (wide_pack,) = [e for k, v in lines.items() if k != worker for e in v
+                    if e[0] == "respect.pack"]
+    assert (wide_pack[3]["bucket_n"], wide_pack[3]["batch"]) == (32, 16)
+    assert wide_pack[3]["path"] == "batched"
     (run,) = [e for e in evs if e[0] == "respect.run"]
     assert (run[3]["bucket_n"], run[3]["bucket_b"]) == (16, 4)
     assert run[3]["impl"] == "scan"
