@@ -10,11 +10,21 @@ policy weights from the release checkpoint's files itself.
   LSTM, decoder LSTM, glimpse attention, pointer scores, visited and
   infeasible nodes masked), run teacher-forced along a served order in
   plain ``jax.numpy``.  Per step it returns the best selectable logit, the
-  served node's logit and the reference's own argmax;
+  served node's logit and the reference's own argmax.  A release with a
+  ``w_sys`` leaf starts its decoder from ``dec0 + profile @ w_sys`` on a
+  system that is not uniform (:func:`profile`);
 * :func:`rho` and :func:`repair` — the order-to-stages map (optimal
   contiguous segmentation under the Coral pipeline cost model,
   lexicographic (bottleneck, latency)) and the deployment repair
   (dependency push, co-consumer rule), in float64 numpy.
+
+A system is a dict: ``n_stages``, ``fixed_overhead_s``, and each of
+``compute_rate``, ``compute_eff``, ``link_bw`` and ``cache_bytes`` as one
+number for every stage or a list of ``n_stages`` numbers, one per stage.
+An optional ``mem_capacity`` (one number or a list) is a hard budget of
+parameter bytes per stage: a segment over it costs
+:data:`CAPACITY_PENALTY_S` more, and the repair moves no node onto a stage
+it would take over its budget.
 """
 
 from __future__ import annotations
@@ -32,6 +42,14 @@ from bench.lib.graphspec import GraphSpec
 
 _MEM_SCALE = 1.0e6
 _ID_MODULUS = 1 << 16
+
+#: added to the time of a stage over its ``mem_capacity``: finite, so that
+#: the DP still orders splits that no budget admits and its backtrack stays
+#: defined, and beyond any stage time a feasible split can have
+CAPACITY_PENALTY_S = 1.0e30
+#: width of the system profile the decoder's start token is conditioned on
+SYS_FEAT_DIM = 16
+_STAGE_KEYS = ("compute_rate", "compute_eff", "link_bw", "cache_bytes")
 
 
 # --------------------------------------------------------------------- #
@@ -81,6 +99,54 @@ def embed(spec: GraphSpec, max_deg: int = 6) -> np.ndarray:
 
 
 # --------------------------------------------------------------------- #
+# the system
+# --------------------------------------------------------------------- #
+def stage_vector(system: dict, key: str) -> np.ndarray:
+    """``system[key]`` as (n_stages,) float64, one entry per stage."""
+    k = int(system["n_stages"])
+    v = np.asarray(system[key], np.float64)
+    if v.ndim and v.shape != (k,):
+        raise ValueError(f"{key} has {v.size} entries for n_stages={k}")
+    return np.broadcast_to(v, (k,)).astype(np.float64)
+
+
+def capacities(system: dict) -> np.ndarray | None:
+    """The hard per-stage parameter budget, or None where there is none."""
+    if system.get("mem_capacity") is None:
+        return None
+    return stage_vector(system, "mem_capacity")
+
+
+def profile(system: dict) -> np.ndarray:
+    """The (16,) float32 hardware profile the decoder is conditioned on.
+
+    All zero for the paper's uniform chain: every cost constant one
+    number and no memory budget.  Otherwise, for each of rate x
+    efficiency, link bandwidth and cache, the min, max and (population)
+    std of the per-stage log2 deviation from the stages' geometric mean;
+    with a memory budget, a 1 at index 9 and the min, max and std of
+    log2(budget / cache) / 8 at 10-12."""
+    feats = np.zeros(SYS_FEAT_DIM, np.float32)
+    if (all(np.ndim(system[key]) == 0 for key in _STAGE_KEYS)
+            and system.get("mem_capacity") is None):
+        return feats
+    rate_eff = (stage_vector(system, "compute_rate")
+                * stage_vector(system, "compute_eff"))
+    cache = stage_vector(system, "cache_bytes")
+    for i, vec in enumerate((rate_eff, stage_vector(system, "link_bw"),
+                             cache)):
+        logs = np.log2(vec)
+        dev = logs - logs.mean()
+        feats[3 * i: 3 * i + 3] = (dev.min(), dev.max(), dev.std())
+    caps = capacities(system)
+    if caps is not None:
+        ratio = np.log2(caps / cache) / 8.0
+        feats[9] = 1.0
+        feats[10:13] = (ratio.min(), ratio.max(), ratio.std())
+    return feats
+
+
+# --------------------------------------------------------------------- #
 # the pointer network, teacher-forced
 # --------------------------------------------------------------------- #
 def _split_dot(passes: int):
@@ -117,13 +183,14 @@ def _lstm(p, x, h, c, dot):
     return jax.nn.sigmoid(o) * jnp.tanh(c), c
 
 
-def _one_graph(params, feats, padj, n_valid, order, probe, dot):
+def _one_graph(params, feats, padj, n_valid, order, probe, sys_feat, dot):
     """Teacher-forced decode of one padded graph.
 
     feats (N, F); padj (N, N) 1.0 where column is a parent of row;
-    order/probe (N,) int32.  Returns per step: best selectable logit,
-    logit of ``order[t]``, whether ``order[t]`` was selectable, the
-    first argmax, and the logit of ``probe[t]``.
+    order/probe (N,) int32; sys_feat the (16,) profile, or None for a
+    decoder that starts from ``dec0`` alone.  Returns per step: best
+    selectable logit, logit of ``order[t]``, whether ``order[t]`` was
+    selectable, the first argmax, and the logit of ``probe[t]``.
     """
     N = feats.shape[0]
     emb = dot(feats, params["w_in"]) + params["b_in"]
@@ -164,23 +231,28 @@ def _one_graph(params, feats, padj, n_valid, order, probe, dot):
         visited = visited.at[served].set(1.0)
         return (h, c, emb[served], visited), out
 
-    init = (h, c, params["dec0"], jnp.zeros(N, jnp.float32))
+    d0 = params["dec0"]
+    if sys_feat is not None:
+        d0 = d0 + vec(sys_feat, params["w_sys"])
+    init = (h, c, d0, jnp.zeros(N, jnp.float32))
     _, outs = jax.lax.scan(dec_step, init, (order, probe))
     return outs
 
 
 @functools.partial(jax.jit, static_argnames=("precision",))
-def _batched(params, feats, padj, n_valid, orders, probes, *, precision):
+def _batched(params, feats, padj, n_valid, orders, probes, sys_feat, *,
+             precision):
     """``precision`` names a product in :data:`DOTS`, or a JAX matmul
     precision (``high``, ``default``) the backend itself runs."""
+    axes = (None, 0, 0, 0, 0, 0, None)
     if precision in DOTS:
         one = functools.partial(_one_graph, dot=DOTS[precision])
-        return jax.vmap(one, in_axes=(None, 0, 0, 0, 0, 0))(
-            params, feats, padj, n_valid, orders, probes)
+        return jax.vmap(one, in_axes=axes)(
+            params, feats, padj, n_valid, orders, probes, sys_feat)
     with jax.default_matmul_precision(precision):
         one = functools.partial(_one_graph, dot=jnp.matmul)
-        return jax.vmap(one, in_axes=(None, 0, 0, 0, 0, 0))(
-            params, feats, padj, n_valid, orders, probes)
+        return jax.vmap(one, in_axes=axes)(
+            params, feats, padj, n_valid, orders, probes, sys_feat)
 
 
 def _pad_size(n: int) -> int:
@@ -190,12 +262,19 @@ def _pad_size(n: int) -> int:
 def pointer_readings(params: dict, specs: list[GraphSpec],
                      orders: list[np.ndarray], probes=None,
                      precision: str = "highest",
-                     block_nodes: int = 16384) -> list:
+                     block_nodes: int = 16384,
+                     system: dict | None = None) -> list:
     """Per graph a dict of (n,) arrays: ``best``, ``served``, ``ok``,
     ``argmax``, ``probe`` (logit of ``probes[i][t]``; of the served node
     when ``probes`` is None).  Graphs run in blocks of one padded size N,
-    ``block_nodes // N`` graphs to a block."""
+    ``block_nodes // N`` graphs to a block.  The decoder is conditioned on
+    ``system``'s :func:`profile` where ``params`` has a ``w_sys`` leaf and
+    the profile is not all zero."""
     jparams = jax.tree.map(jnp.asarray, params)
+    sys_feat = None
+    if system is not None and "w_sys" in params:
+        prof = profile(system)
+        sys_feat = jnp.asarray(prof) if prof.any() else None
     out: list = [None] * len(specs)
     groups: dict[int, list[int]] = {}
     for i, s in enumerate(specs):
@@ -222,7 +301,7 @@ def pointer_readings(params: dict, specs: list[GraphSpec],
                 ords[r, : s.n] = np.clip(o, 0, s.n - 1)
                 p = o if probes is None else np.asarray(probes[i], np.int64)
                 prb[r, : s.n] = np.clip(p, 0, s.n - 1)
-            res = _batched(jparams, feats, padj, nv, ords, prb,
+            res = _batched(jparams, feats, padj, nv, ords, prb, sys_feat,
                            precision=precision)
             res = [np.asarray(x) for x in res]
             for r, i in enumerate(part):
@@ -244,10 +323,42 @@ def logit_gaps(readings: dict, order: np.ndarray, n: int) -> np.ndarray:
 
 
 # --------------------------------------------------------------------- #
-# rho and repair (uniform pipeline)
+# rho and repair
 # --------------------------------------------------------------------- #
 def _children(spec: GraphSpec) -> list[list[int]]:
     return spec.children()
+
+
+def _cost_tables(bbytes, seg_flops, seg_params, occupied, system: dict,
+                 cost_dtype) -> list[np.ndarray]:
+    """One (n + 1, n + 1) table per stage: entry [i, j] is the time of the
+    stage holding order positions [i, j).  Where every stage has the same
+    constants, the stages share one table.  Every table would hold the
+    same numbers; building one instead of ``n_stages`` halves the time of
+    :func:`schedule` on a Table-I graph, which every run's check pays."""
+    k = int(system["n_stages"])
+    ovh = float(system["fixed_overhead_s"])
+    rate = (stage_vector(system, "compute_rate")
+            * stage_vector(system, "compute_eff"))
+    bw = stage_vector(system, "link_bw")
+    cache = stage_vector(system, "cache_bytes")
+    caps = capacities(system)
+
+    def table(s: int) -> np.ndarray:
+        cost = (bbytes[:, None] / bw[s] + seg_flops / rate[s]
+                + np.maximum(0.0, seg_params - cache[s]) / bw[s]
+                + np.where(occupied, ovh, 0.0))
+        if caps is not None:
+            cost = cost + np.where(seg_params > caps[s],
+                                   CAPACITY_PENALTY_S, 0.0)
+        cost[seg_flops < 0] = np.inf
+        if cost_dtype is not None:
+            cost = cost.astype(cost_dtype).astype(np.float64)
+        return cost
+
+    same = all(np.all(v == v[0]) for v in (rate, bw, cache)) and (
+        caps is None or np.all(caps == caps[0]))
+    return [table(0)] * k if same else [table(s) for s in range(k)]
 
 
 def rho(spec: GraphSpec, order: np.ndarray, system: dict,
@@ -255,11 +366,13 @@ def rho(spec: GraphSpec, order: np.ndarray, system: dict,
     """Optimal contiguous segmentation of ``order`` into
     ``system["n_stages"]`` stages; per-node stage assignment.
 
-    Stage time = crossing bytes / link_bw + flops / (rate * eff)
-    + max(0, params - cache) / link_bw + fixed overhead if occupied.
-    Among splits within a relative 1e-12 of the least bottleneck, the
-    least latency wins, and among those the first split point.  Costs are
-    float64; ``cost_dtype`` rounds the cost table to a lower precision
+    Time of stage s = crossing bytes / link_bw[s] + flops / (rate[s] *
+    eff[s]) + max(0, params - cache[s]) / link_bw[s] + fixed overhead if
+    occupied, + :data:`CAPACITY_PENALTY_S` if params exceed
+    mem_capacity[s].  Stage s of the DP reads stage s's table.  Among
+    splits within a relative 1e-12 of the least bottleneck, the least
+    latency wins, and among those the first split point.  Costs are
+    float64; ``cost_dtype`` rounds each cost table to a lower precision
     first (the control)."""
     n = spec.n
     k = int(system["n_stages"])
@@ -279,21 +392,16 @@ def rho(spec: GraphSpec, order: np.ndarray, system: dict,
     seg_flops = flops[None, :] - flops[:, None]
     seg_params = params[None, :] - params[:, None]
     occupied = (np.arange(n + 1)[None, :] - np.arange(n + 1)[:, None]) > 0
-    rate = float(system["compute_rate"]) * float(system["compute_eff"])
-    bw = float(system["link_bw"])
-    cost = (bbytes[:, None] / bw + seg_flops / rate
-            + np.maximum(0.0, seg_params - float(system["cache_bytes"])) / bw
-            + np.where(occupied, float(system["fixed_overhead_s"]), 0.0))
-    cost[seg_flops < 0] = np.inf
-    if cost_dtype is not None:
-        cost = cost.astype(cost_dtype).astype(np.float64)
+    tables = _cost_tables(bbytes, seg_flops, seg_params, occupied, system,
+                          cost_dtype)
 
-    f_b = cost[0].copy()
-    f_l = cost[0].copy()
+    f_b = tables[0][0].copy()
+    f_l = tables[0][0].copy()
     args = np.zeros((k, n + 1), np.int64)
     cols = np.arange(n + 1)
     with np.errstate(invalid="ignore"):
         for s in range(1, k):
+            cost = tables[s]
             b = np.maximum(f_b[:, None], cost)
             lat = f_l[:, None] + cost
             m = b.min(axis=0)
@@ -324,15 +432,23 @@ def _dependency_push(spec: GraphSpec, assign: np.ndarray, k: int) -> np.ndarray:
 
 
 def repair(spec: GraphSpec, assign: np.ndarray, k: int,
-           max_iters: int = 8) -> np.ndarray:
+           max_iters: int = 8, caps: np.ndarray | None = None) -> np.ndarray:
     """Dependency push, then the co-consumer rule (every child of a
     multi-consumer node pulled to the earliest child stage its parents
     allow) alternated with the push to a fixed point (at most
-    ``max_iters`` rounds), then a last push."""
+    ``max_iters`` rounds), then a last push.
+
+    With ``caps``, the per-stage parameter budget, the co-consumer rule
+    skips a pull that would take its target stage over budget.  Stage
+    loads are counted afresh from the assignment each round enters with,
+    and follow each move, producers in index order, children in order."""
     children = _children(spec)
 
     def co_consumer(a):
         out = a.copy()
+        if caps is not None:
+            loads = np.zeros(k)
+            np.add.at(loads, out, spec.param_bytes)
         for u in range(spec.n):
             ch = children[u]
             if len(ch) < 2:
@@ -340,7 +456,14 @@ def repair(spec: GraphSpec, assign: np.ndarray, k: int,
             earliest = min(out[v] for v in ch)
             for v in ch:
                 lo = max((out[p] for p in spec.parents[v]), default=0)
-                out[v] = max(earliest, lo)
+                to = max(earliest, lo)
+                if caps is not None and to != out[v]:
+                    pb = spec.param_bytes[v]
+                    if loads[to] + pb > caps[to]:
+                        continue
+                    loads[to] += pb
+                    loads[out[v]] -= pb
+                out[v] = to
         return out
 
     out = _dependency_push(spec, assign, k)
@@ -354,9 +477,11 @@ def repair(spec: GraphSpec, assign: np.ndarray, k: int,
 
 def schedule(spec: GraphSpec, order: np.ndarray, system: dict,
              cost_dtype=None) -> np.ndarray:
-    """The deployed assignment for a served order: repair(rho(order))."""
+    """The deployed assignment for a served order: repair(rho(order)),
+    the repair held to the system's memory budget."""
     k = int(system["n_stages"])
-    return repair(spec, rho(spec, order, system, cost_dtype), k)
+    return repair(spec, rho(spec, order, system, cost_dtype), k,
+                  caps=capacities(system))
 
 
 def valid_schedule(spec: GraphSpec, assign: np.ndarray, k: int) -> bool:
@@ -372,9 +497,11 @@ def valid_schedule(spec: GraphSpec, assign: np.ndarray, k: int) -> bool:
 def objective(spec: GraphSpec, assign: np.ndarray, system: dict
               ) -> tuple[float, float]:
     """(bottleneck, latency) seconds of a schedule on the pipeline: per
-    stage, the bytes of every tensor crossing into it over the link,
-    its flops over rate x efficiency, its parameters beyond the cache
-    over the link, and the fixed overhead if it holds a node."""
+    stage, with that stage's constants, the bytes of every tensor crossing
+    into it over its link, its flops over rate x efficiency, its
+    parameters beyond its cache over its link, the fixed overhead if it
+    holds a node, and :data:`CAPACITY_PENALTY_S` if its parameters exceed
+    its memory budget."""
     a = np.asarray(assign, np.int64)
     k = int(system["n_stages"])
     params = np.zeros(k)
@@ -389,11 +516,14 @@ def objective(spec: GraphSpec, assign: np.ndarray, system: dict
     for u in range(spec.n):
         if last[u] > a[u]:
             inb[a[u] + 1: last[u] + 1] += spec.out_bytes[u]
-    bw = float(system["link_bw"])
+    bw = stage_vector(system, "link_bw")
     occupied = np.zeros(k)
     np.add.at(occupied, a, 1.0)
-    t = (inb / bw + flops / (float(system["compute_rate"])
-                             * float(system["compute_eff"]))
-         + np.maximum(0.0, params - float(system["cache_bytes"])) / bw
+    t = (inb / bw + flops / (stage_vector(system, "compute_rate")
+                             * stage_vector(system, "compute_eff"))
+         + np.maximum(0.0, params - stage_vector(system, "cache_bytes")) / bw
          + np.where(occupied > 0, float(system["fixed_overhead_s"]), 0.0))
+    caps = capacities(system)
+    if caps is not None:
+        t = t + np.where(params > caps, CAPACITY_PENALTY_S, 0.0)
     return float(t.max()), float(t.sum())

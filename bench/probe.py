@@ -106,7 +106,8 @@ def readings(args) -> None:
                "program": compare(params, specs, orders, assigns,
                                   cfg["system"], n_assign)}
         for prec in ("high", "default"):
-            row[f"reference_{prec}"] = control_gap(params, specs, orders, prec)
+            row[f"reference_{prec}"] = control_gap(params, specs, orders, prec,
+                                                   cfg["system"])
         row["reference_rho_bf16"] = control_excess(
             specs, orders, cfg["system"], n_assign, ml_dtypes.bfloat16)
         if bf16 is not None:

@@ -468,13 +468,14 @@ def compare(params: dict, specs, orders, assigns, system: dict,
     """The numbers ``correct`` compares for a list of served schedules:
 
     * ``logit_gap_max``: how far below the reference's best logit a served
-      node lies, widest over every step of every schedule;
+      node lies, widest over every step of every schedule, the decoder
+      conditioned on ``system`` as the program's is;
     * ``invalid_schedules``: schedules that break a dependency, leave the
       stage range, or whose order is not one the reference could decode;
     * ``bottleneck_excess``: for the first ``n_assign``, the most by which
       a served schedule's pipeline bottleneck exceeds that of the
       reference's ``repair(rho(order))``, relative to it."""
-    readings = ref.pointer_readings(params, specs, orders)
+    readings = ref.pointer_readings(params, specs, orders, system=system)
     k = int(system["n_stages"])
     gap = excess = 0.0
     invalid = 0
@@ -505,12 +506,16 @@ def control_excess(specs, orders, system: dict, n_assign: int,
     return worst
 
 
-def control_gap(params: dict, specs, orders, precision: str) -> float:
+def control_gap(params: dict, specs, orders, precision: str,
+                system: dict | None = None) -> float:
     """Widest gap, under the reference, of the node that the reference at
-    ``precision`` puts first, at each position of the served orders."""
-    low = ref.pointer_readings(params, specs, orders, precision=precision)
+    ``precision`` puts first, at each position of the served orders, both
+    decoders conditioned on ``system``."""
+    low = ref.pointer_readings(params, specs, orders, precision=precision,
+                               system=system)
     hi = ref.pointer_readings(params, specs, orders,
-                              probes=[r["argmax"] for r in low])
+                              probes=[r["argmax"] for r in low],
+                              system=system)
     return max(float(np.max(h["best"].astype(np.float64) - h["probe"]))
                for h in hi)
 

@@ -1,7 +1,8 @@
 """The controls: the reference in a lower precision, put in the program's
 place, reads not correct where the program reads correct: the pointer
 network's products for the logit gap, rho's cost table (bfloat16) for the
-bottleneck excess.
+bottleneck excess, on the configurations' uniform chain and on a
+heterogeneous one.
 
 On the chip the control runs at the cell's own size through
 ``bench/probe.py readings`` (the TPU's own ``high`` and ``default``
@@ -11,12 +12,14 @@ run holds, the one-pass product flips a served node where the three-pass
 one does not yet (it needs the cell's whole window, see PERF.md).
 """
 
+import dataclasses
 import json
 import sys
 from pathlib import Path
 
 import ml_dtypes
 import numpy as np
+import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
@@ -27,26 +30,39 @@ from bench.lib.graphspec import to_program  # noqa: E402
 from bench.runners.serve import compare, control_excess, control_gap  # noqa: E402
 
 
-def test_lower_precision_reference_fails_the_limit():
+def _system(chain: str, cfg: dict) -> dict:
+    """The configuration's uniform chain, or a seeded heterogeneous one
+    (``eval.scenarios.hetero_system``): the bottleneck excess limit holds
+    for both."""
+    if chain == "uniform":
+        return cfg["system"]
+    from repro.eval.scenarios import hetero_system
+    return {k: (list(v) if isinstance(v, tuple) else v)
+            for k, v in dataclasses.asdict(hetero_system(4, 16)).items()}
+
+
+@pytest.mark.parametrize("chain", ["uniform", "hetero"])
+def test_lower_precision_reference_fails_the_limit(chain):
     from repro.core import PipelineSystem, RespectScheduler
     cfg = json.loads((ROOT / "bench/configs/coral-table1-k4.json").read_text())
     traffic = json.loads(
         (ROOT / "bench/traffic/table1-open80.json").read_text())
+    system = _system(chain, cfg)
     specs = table1_variants.make(np.random.default_rng([5, 1]), 10,
                                  traffic["generator_args"])
     sched = RespectScheduler.from_release(ROOT / cfg["release"])
     res = sched.schedule_many([to_program(s) for s in specs], 4,
-                              PipelineSystem(**cfg["system"]), use_cache=False)
+                              PipelineSystem(**system), use_cache=False)
     orders = [r["order"] for r in res]
     params = ref.load_params(ROOT / cfg["release"])
     limit = cfg["correct"]["logit_gap_max"]
     program = compare(params, specs, orders, [r["assignment"] for r in res],
-                      cfg["system"], n_assign=2)
+                      system, n_assign=2)
     assert program["logit_gap_max"] <= limit
     assert program["invalid_schedules"] == 0
     assert program["bottleneck_excess"] <= cfg["correct"]["bottleneck_excess"]
-    assert control_gap(params, specs, orders, "bf16x1") > limit
-    assert control_excess(specs, orders, cfg["system"], len(specs),
+    assert control_gap(params, specs, orders, "bf16x1", system) > limit
+    assert control_excess(specs, orders, system, len(specs),
                           ml_dtypes.bfloat16) > \
         cfg["correct"]["bottleneck_excess"]
 

@@ -1,5 +1,6 @@
 """The harness: it refuses to run without a chip or outside a program
-checkout, and a run whose served answers are altered reads not correct."""
+checkout, a run whose served answers are altered reads not correct, and a
+cell on a heterogeneous chain runs and reads correct."""
 
 import json
 import os
@@ -86,3 +87,25 @@ def test_altered_answer_reads_not_correct():
         broken["checks"]["logit_gap_max"]["value"] > \
         broken["checks"]["logit_gap_max"]["limit"]
     assert list(broken)[-1] == "checks"
+
+
+#: a 4-stage Coral chain whose last two stages sit behind USB 2.0 (about
+#: 40 MB/s effective) and the first two behind USB 3.0
+USB2_CHAIN = {"compute_rate": [4.0e12] * 4, "compute_eff": [0.25] * 4,
+              "link_bw": [320.0e6, 320.0e6, 40.0e6, 40.0e6],
+              "cache_bytes": [8388608.0] * 4}
+
+
+def test_heterogeneous_cell_reads_correct():
+    from bench.lib.cell import run_cell
+    from bench.lib.spec import load_cell
+    parts = load_cell("table1-k4.open80")
+    parts["config"]["system"] = dict(parts["config"]["system"], **USB2_CHAIN)
+    parts["config"]["service"]["max_batch"] = 2
+    parts["traffic"]["rate_per_s"] = 6.0
+    line = run_cell(parts, 3000000002, 1.0, False, time.perf_counter(),
+                    require_tpu=False)
+    assert line["correct"], json.dumps(line["checks"])
+    assert set(line["checks"]) == {"logit_gap_max", "invalid_schedules",
+                                   "bottleneck_excess", "unanswered"}
+    assert line["attempted"] > 0 and line["failed"] == 0
